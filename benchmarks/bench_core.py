@@ -106,7 +106,9 @@ def _columnar_device_chain(backend: str, kernel: str, ndev: int = 2):
     from repro.columnar import Schema, device_op
     from repro.core.operators import OpSpec
 
-    schema = Schema.of(*(["i8"] * COL_WIDTH))
+    # jax computes 32-bit columns unless x64 is on (plan rule PV413)
+    code = "i4" if backend == "jax" else "i8"
+    schema = Schema.of(*([code] * COL_WIDTH))
     ops = [OpSpec("widen", "stateless", _col_widen, cost_us=1.0)]
     for i, (a, b) in zip(range(ndev), ((3, -1), (1, 5))):
         ops.append(device_op(

@@ -1,4 +1,4 @@
-"""Plan-time ordering-safety rule catalog (rules PV401–PV408, PV410–PV412).
+"""Plan-time ordering-safety rule catalog (rules PV401–PV408, PV410–PV414).
 
 :meth:`repro.core.api.PhysicalPlan.verify` delegates here.  The rules assert
 the structural invariants that make a plan's parallel execution externally
@@ -46,8 +46,16 @@ builds, but a hand-built or deserialized-and-edited plan can violate them:
   ordered egress can livelock behind them).
 - **PV412** — columnar claims need fixed-width schemas: when the plan arms
   the columnar path (or cuts a device stage), every device operator must
-  declare a fixed-width schema (``schema_width >= 1``) — the block codec
+  declare a fixed-width schema (at least one field code) — the block codec
   cannot type a column vector without one.
+- **PV413** — a device stage computes in the dtypes its schema names: a
+  jax device stage may declare 64-bit fields (``i8``/``f8``) only when its
+  workers run jax in x64 mode (``ring["x64"]``); otherwise jax would
+  compute in 32 bits while the NumPy reference computes in 64.
+- **PV414** — one chip-owning process per TPU host: when a jax backend
+  there opens a TPU (``ring["tpu_host"]``), the jax device stages' widths
+  must sum to at most 1.  A chip admits one process at a time; a second
+  one fails to open it or hangs.
 
 The module deliberately imports nothing from :mod:`repro.core` — it reads
 the plan duck-typed — so ``core.api`` can import it lazily with no cycle.
@@ -57,7 +65,15 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import List, Optional
 
-CATALOG_VERSION = 4
+CATALOG_VERSION = 5
+
+#: PV414's message; the process runtime raises it too when a device worker
+#: reports a TPU under a plan that was never verified
+ONE_CHIP_OWNER = (
+    "one chip-owning process per TPU host: {n} jax device worker processes "
+    "would each open the chip, which admits one process; use one device "
+    "stage with device_workers=1"
+)
 
 
 @dataclass(frozen=True)
@@ -296,16 +312,40 @@ def verify_plan(plan) -> List[PlanViolation]:
             )
     if dev_stages or ring.get("columnar"):
         for op in plan.ops:
-            if op.kind != "device":
-                continue
-            width = getattr(op, "schema_width", None)
-            if not width or width < 1:
+            if op.kind == "device" and not getattr(op, "schema", None):
                 v.append(
                     PlanViolation(
                         rule="PV412",
                         op=op.name,
                         message="device operator declares no fixed-width "
-                        "columnar schema (schema_width must be >= 1)",
+                        "columnar schema (needs at least one field code)",
                     )
                 )
+    jax_stages = [
+        s for s in dev_stages if getattr(s, "device_backend", None) == "jax"
+    ]
+    schemas = {op.name: getattr(op, "schema", None) or () for op in plan.ops}
+    if not ring.get("x64"):
+        for s in jax_stages:
+            wide = [c for name in s.ops for c in schemas.get(name, ())
+                    if c in ("i8", "f8")]
+            if wide:
+                v.append(
+                    PlanViolation(
+                        rule="PV413",
+                        stage=s.index,
+                        message=f"jax device stage declares 64-bit fields "
+                        f"{wide} but its workers run jax without x64: it "
+                        "would compute in 32 bits; declare i4/f4 fields or "
+                        "set JAX_ENABLE_X64=1",
+                    )
+                )
+    owners = sum(s.workers for s in jax_stages)
+    if ring.get("tpu_host") and owners > 1:
+        v.append(
+            PlanViolation(
+                rule="PV414",
+                message=ONE_CHIP_OWNER.format(n=owners),
+            )
+        )
     return v

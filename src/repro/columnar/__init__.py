@@ -29,6 +29,9 @@ _LAZY = {
     "resolve_backend": ".device",
     "have_jax": ".device",
     "jax_fork_hazard": ".device",
+    "host_has_tpu": ".device",
+    "x64_enabled": ".device",
+    "configure_compile_cache": ".device",
     "KERNELS": ".device",
 }
 

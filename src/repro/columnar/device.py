@@ -18,17 +18,28 @@ backends because XLA fuses multiply-add (see ``docs/columnar.md``).
 
 Kernels are elementwise column maps ``fn(*cols) -> cols`` registered in
 :data:`KERNELS` under a name; each entry supplies a NumPy factory and a
-jax factory.  ``affine_pallas`` is the pallas-backed entry — it lowers
-through :func:`pl.pallas_call` (interpret mode, so it runs on CPU jax).
-Batch boundaries never change results precisely *because* kernels are
-elementwise; that is what lets the runtime flush partial batches on
-barriers, EOF, or upstream stalls without forking the output.
+jax factory.  ``affine_pallas`` is the pallas-backed entry — compiled by
+Mosaic on TPU, run by the Pallas interpreter elsewhere
+(:func:`repro.kernels.platform.pallas_call`).  Batch boundaries never
+change results precisely *because* kernels are elementwise; that is what
+lets the runtime flush partial batches on barriers, EOF, or upstream
+stalls without forking the output.
+
+A jax device stage computes in the dtypes its schema names: 64-bit fields
+need jax's x64 mode (plan rule PV413), and a process that brings up a jax
+backend on a TPU host owns the chip, so a plan may hold at most one such
+process there (PV414).  Both facts are read here without initializing a
+jax backend (:func:`x64_enabled`, :func:`host_has_tpu`).
 """
 from __future__ import annotations
 
 import functools
+import os
+import sys
+import time
 from collections import deque
-from typing import Any, Callable, Deque, List, Optional, Sequence, Tuple
+from pathlib import Path
+from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -36,6 +47,10 @@ from ..core.operators import DEVICE, OpSpec
 from .block import ColumnBlock, Schema
 
 Params = Tuple[Tuple[str, Any], ...]
+
+#: the compile cache's one path when ``JAX_COMPILATION_CACHE_DIR`` is unset
+#: (fixed: the path is part of the cache key, so a moving directory never hits)
+CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
 def have_jax() -> bool:
@@ -68,6 +83,52 @@ def jax_fork_hazard() -> bool:
         return bool(xb.backends_are_initialized())
     except Exception:
         return False
+
+
+def host_has_tpu() -> bool:
+    """Whether a jax backend brought up in this process (or a child it
+    forks) would open a TPU — read from ``JAX_PLATFORMS`` (or the imported
+    jax config) and the PCI bus, without initializing a backend."""
+    if "jax" in sys.modules:
+        import jax
+
+        names = jax.config.jax_platforms
+    else:
+        names = os.environ.get("JAX_PLATFORMS")
+    if names:
+        return names.split(",")[0].strip() == "tpu"
+    try:
+        from jax._src.hardware_utils import num_available_tpu_chips_and_device_id
+    except ImportError:
+        return False
+    return num_available_tpu_chips_and_device_id()[0] > 0
+
+
+def x64_enabled() -> bool:
+    """Whether jax computes 64-bit dtypes in this process (and in the
+    device workers it forks, which inherit its config and environment)."""
+    if "jax" in sys.modules:
+        import jax
+
+        return bool(jax.config.jax_enable_x64)
+    flag = os.environ.get("JAX_ENABLE_X64", "").strip().lower()
+    return flag in ("1", "true", "t", "yes", "y", "on")
+
+
+def configure_compile_cache() -> str:
+    """Turn on jax's persistent compile cache before the first compile and
+    return its directory: ``JAX_COMPILATION_CACHE_DIR`` when set (jax reads
+    it itself; no other directory is set), else :data:`CACHE_DIR`.  Every
+    compile is cached, however short, so a re-forked device worker finds
+    the kernels its predecessor compiled."""
+    import jax
+
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(CACHE_DIR)
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    return path
 
 
 def resolve_backend(name: Optional[str] = "auto") -> str:
@@ -124,27 +185,51 @@ def _jax_square(params: Params) -> Callable[..., tuple]:
     return fn
 
 
+_LANES = 128  # a column is laid out lane-dense as (rows, 128)
+_BLOCK_ROWS = 512  # rows per grid step: a (512, 128) 32-bit tile is 256 KiB
+
+
 def _pallas_affine_body(x_ref, o_ref, *, a, b):
     o_ref[...] = x_ref[...] * a + b
 
 
-def _jax_affine_pallas(params: Params) -> Callable[..., tuple]:
+def affine_pallas(col, a, b):
+    """``col * a + b`` over a 1-D column as a gridded Pallas kernel.
+
+    The column is padded to whole lanes and viewed as ``(rows, 128)``; the
+    grid walks ``(512, 128)`` tiles, so VMEM holds a few tiles whatever the
+    column length.  A column of at most 512 rows is one full-array block."""
     import jax
+    import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
+    from ..kernels.platform import pallas_call
+
+    n = col.shape[0]
+    rows = -(-n // _LANES)
+    block = min(rows, _BLOCK_ROWS)
+    rows = -(-rows // block) * block
+    x = col
+    if rows * _LANES != n:
+        x = jnp.pad(col, (0, rows * _LANES - n))
+    x = x.reshape(rows, _LANES)
+    spec = pl.BlockSpec((block, _LANES), lambda i: (i, 0))
+    out = pallas_call(
+        functools.partial(_pallas_affine_body, a=a, b=b),
+        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        grid=(rows // block,),
+        in_specs=[spec],
+        out_specs=spec,
+    )(x)
+    return out.reshape(-1)[:n]
+
+
+def _jax_affine_pallas(params: Params) -> Callable[..., tuple]:
     kw = dict(params)
     a, b = kw.get("a", 1), kw.get("b", 0)
-    body = functools.partial(_pallas_affine_body, a=a, b=b)
 
     def fn(*cols):
-        return tuple(
-            pl.pallas_call(
-                body,
-                out_shape=jax.ShapeDtypeStruct(c.shape, c.dtype),
-                interpret=True,
-            )(c)
-            for c in cols
-        )
+        return tuple(affine_pallas(c, a, b) for c in cols)
 
     return fn
 
@@ -237,7 +322,16 @@ class DeviceExecutor:
     the original per-unit blocks — serials and marks untouched — so the
     caller publishes each unit exactly as it arrived (the replay-identity
     requirement: re-fed units re-derive identical publishes regardless of
-    how device batches regrouped them)."""
+    how device batches regrouped them).
+
+    A dispatch never holds more than ``batch`` rows unless one unit alone
+    does.  The jax backend pads every dispatch to a whole number of
+    batches, so a stream compiles the kernel once, at construction,
+    however its partial flushes fall.  ``device`` describes where the jax
+    backend came up: ``platform``, ``kind`` and device ``count`` as jax
+    reports them, with the seconds spent tracing and lowering
+    (``lower_s``) and compiling (``compile_s``, what the persistent cache
+    saves) over ``compiles`` shapes; ``None`` on the NumPy backend."""
 
     def __init__(
         self,
@@ -253,17 +347,70 @@ class DeviceExecutor:
         self.batch = max(int(spec.device_batch or batch), 1)
         self.inflight_limit = max(int(inflight), 1)
         self.backend = resolve_backend(spec.device_backend or backend)
-        fn = make_kernel(kernel, self.backend, params)
+        self._fn = make_kernel(kernel, self.backend, params)
+        self.device: Optional[Dict[str, Any]] = None
+        self._executables: Dict[int, Any] = {}
         if self.backend == "jax":
             import jax
 
-            fn = jax.jit(fn)
-        self._fn = fn
+            self.device = self._bring_up(spec.name)
+            self._fn = jax.jit(self._fn)
+            self._executable(self.batch)
         self._pending: List[ColumnBlock] = []
         self._pending_rows = 0
-        self._inflight: Deque[Tuple[Any, list]] = deque()
+        self._inflight: Deque[Tuple[Any, list, int]] = deque()
         #: dispatched batch count (observability)
         self.dispatches = 0
+
+    def _bring_up(self, name: str) -> Dict[str, Any]:
+        import jax
+
+        wide = [c for c, dt in zip(self.schema.codes, self.schema.dtypes)
+                if dt.itemsize == 8]
+        if wide and not jax.config.jax_enable_x64:
+            raise ValueError(
+                f"device op {name!r} declares 64-bit fields {wide} but jax "
+                "computes in 32 bits here (x64 off); declare i4/f4 fields "
+                "or set JAX_ENABLE_X64=1"
+            )
+        try:
+            devices = jax.devices()
+        except RuntimeError as exc:
+            raise RuntimeError(
+                f"device op {name!r}: the jax backend did not come up "
+                f"({exc}); is the chip held by another process?"
+            ) from exc
+        if devices[0].platform != "cpu":
+            # before the first compile; XLA:CPU cache hits save little and
+            # warn about host features, so CPU runs leave the cache off
+            configure_compile_cache()
+        return {
+            "platform": devices[0].platform,
+            "kind": devices[0].device_kind,
+            "count": len(devices),
+            "lower_s": 0.0,
+            "compile_s": 0.0,
+            "compiles": 0,
+        }
+
+    def _executable(self, rows: int):
+        """The kernel compiled for ``rows``-row columns (compiled once per
+        shape, timed into ``device``)."""
+        exe = self._executables.get(rows)
+        if exe is None:
+            import jax
+
+            t0 = time.perf_counter()
+            lowered = self._fn.lower(*(
+                jax.ShapeDtypeStruct((rows,), dt) for dt in self.schema.dtypes
+            ))
+            t1 = time.perf_counter()
+            exe = lowered.compile()
+            self.device["lower_s"] += t1 - t0
+            self.device["compile_s"] += time.perf_counter() - t1
+            self.device["compiles"] += 1
+            self._executables[rows] = exe
+        return exe
 
     @property
     def pending_rows(self) -> int:
@@ -278,11 +425,12 @@ class DeviceExecutor:
     def submit(self, block: ColumnBlock) -> List[ColumnBlock]:
         """Absorb one unit's block; returns any units whose batches
         completed (possibly none, never blocks unless the window is full)."""
+        if self._pending and self._pending_rows + len(block) > self.batch:
+            self._dispatch()
         self._pending.append(block)
         self._pending_rows += len(block)
-        if self._pending_rows < self.batch:
-            return []
-        self._dispatch()
+        if self._pending_rows >= self.batch:
+            self._dispatch()
         ready: List[ColumnBlock] = []
         while len(self._inflight) > self.inflight_limit:
             ready.extend(self._pop())
@@ -299,31 +447,39 @@ class DeviceExecutor:
         return out
 
     def _dispatch(self) -> None:
-        big = ColumnBlock.concat(self._pending)
-        units = [(b.serials, b.marks) for b in self._pending]
+        blocks = self._pending
+        n = self._pending_rows
+        units = [(b.serials, b.marks) for b in blocks]
         self._pending = []
         self._pending_rows = 0
         if self.backend == "jax":
-            import jax.numpy as jnp
-
-            # fresh np.concatenate output: safe to alias zero-copy, the
-            # host never mutates it after dispatch
-            outs = self._fn(*(jnp.asarray(c) for c in big.columns))
+            rows = -(-n // self.batch) * self.batch
+            cols = []
+            for i, dt in enumerate(self.schema.dtypes):
+                # fresh buffer, zero-padded to the compiled shape: safe to
+                # alias zero-copy, the host never mutates it after dispatch
+                col = np.zeros(rows, dt)
+                np.concatenate([b.columns[i] for b in blocks], out=col[:n])
+                cols.append(col)
+            outs = self._executable(rows)(*cols)
         else:
-            outs = self._fn(*big.columns)
+            outs = self._fn(*ColumnBlock.concat(blocks).columns)
         self.dispatches += 1
-        self._inflight.append((outs, units))
+        self._inflight.append((outs, units, n))
 
     def _pop(self) -> List[ColumnBlock]:
-        outs, units = self._inflight.popleft()
+        outs, units, n = self._inflight.popleft()
         if self.backend == "jax":
             import jax
 
             outs = jax.block_until_ready(outs)
-        cols = [
-            np.asarray(o).astype(dt, copy=False)
-            for o, dt in zip(outs, self.schema.dtypes)
-        ]
+        cols = [np.asarray(o)[:n] for o in outs]
+        for c, dt in zip(cols, self.schema.dtypes):
+            if c.dtype != dt:
+                raise TypeError(
+                    f"device kernel returned {c.dtype} for a {dt} column; "
+                    "a device stage computes in its schema's dtypes"
+                )
         blocks: List[ColumnBlock] = []
         off = 0
         for serials, marks in units:

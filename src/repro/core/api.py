@@ -493,8 +493,8 @@ class PlannedOp:
     relative input ``flow`` (tuples per source tuple), per-tuple ``cost_us``,
     declared ``selectivity``, the ``load_share`` fraction of total predicted
     work, and the intrinsic parallelism cap ``max_dop`` (``None`` =
-    unbounded — stateless operators).  ``schema_width`` is the declared
-    columnar field count of ``device``-kind operators (``None``
+    unbounded — stateless operators).  ``schema`` lists the declared
+    columnar field codes of ``device``-kind operators (``None``
     otherwise)."""
 
     name: str
@@ -504,7 +504,7 @@ class PlannedOp:
     flow: float
     load_share: float
     max_dop: Optional[int] = None
-    schema_width: Optional[int] = None
+    schema: Optional[List[str]] = None
 
 
 @dataclass
@@ -516,7 +516,8 @@ class PlannedStage:
     ``flow`` / ``load_share`` driving the allocation, and whether the stage
     participates in epoch checkpointing (``checkpointed`` — keyed, stateful,
     and device stages with a non-zero ``checkpoint_interval`` and crash
-    restarts on)."""
+    restarts on).  ``device_backend`` is a device stage's resolved kernel
+    backend (``jax`` or ``numpy``; ``None`` for other kinds)."""
 
     index: int
     kind: str
@@ -527,6 +528,7 @@ class PlannedStage:
     flow: float
     load_share: float
     checkpointed: bool = False
+    device_backend: Optional[str] = None
 
 
 class PhysicalPlan:
@@ -847,8 +849,11 @@ class JobResult:
     counters (``recoveries`` counts completed crash recoveries — group
     restores and router re-forks; ``dead_letters`` holds the
     :class:`~.faults.DeadLetter` tuples quarantined under the
-    ``on_error="dead_letter"`` policy).  ``handle()`` wraps it in the
-    legacy-shaped proxy."""
+    ``on_error="dead_letter"`` policy).  ``devices`` has one row per jax
+    device worker of a process run — ``stage``, ``worker``, and the
+    ``platform``, device ``kind`` and ``count`` its backend reported, with
+    its ``lower_s``, ``compile_s``, ``compiles`` and ``dispatches``.  ``handle()`` wraps
+    it in the legacy-shaped proxy."""
 
     outputs: list
     report: RunReport
@@ -859,6 +864,7 @@ class JobResult:
     restarts: int = 0
     recoveries: int = 0
     dead_letters: list = field(default_factory=list)
+    devices: list = field(default_factory=list)
     target: Any = field(default=None, repr=False)  # executed pipeline/runtime
 
     def handle(self) -> "JobHandle":
@@ -1321,6 +1327,7 @@ class _ProcessSession(Session):
             "restarts": rt.restarts,
             "recoveries": rt.recoveries,
             "dead_letters": len(rt.dead_letters),
+            "devices": rt.device_reports(),
             "grows": rt.grows,
             "shrinks": rt.shrinks,
             "resize_stalls": list(rt.resize_stalls),
@@ -1447,7 +1454,7 @@ class Engine:
                 target=pipe,
             )
 
-        rt = self._make_process_runtime(nodes, edge_list, stage_widths=pinned)
+        rt = self._process_runtime(plan, nodes, edge_list, pinned)
         report = rt.run(source, drain_timeout=drain_timeout)
         op_rows, routing = graph_flows(nodes, edge_list, cfg.cost_priors)
         executed = self._describe_process(
@@ -1458,7 +1465,7 @@ class Engine:
             markers=list(rt.markers), egress_count=rt.egress_count,
             replans=rt.replans, restarts=rt.restarts,
             recoveries=rt.recoveries, dead_letters=list(rt.dead_letters),
-            target=rt,
+            devices=rt.device_reports(), target=rt,
         )
 
     # ----------------------------------------------------------------- open
@@ -1471,7 +1478,7 @@ class Engine:
         calibrate on — and rely on elastic replanning to adapt live.
         """
         cfg = self.config
-        _plan, nodes, edge_list, chain_specs, pinned = self._resolve(
+        plan, nodes, edge_list, chain_specs, pinned = self._resolve(
             plan_or_graph, edges
         )
         if cfg.backend == "thread":
@@ -1479,9 +1486,7 @@ class Engine:
                 nodes, edge_list, chain_specs, collect=True
             )
             return _ThreadSession(pipe, rt)
-        rt = self._make_process_runtime(
-            nodes, edge_list, stage_widths=pinned, collect=True
-        )
+        rt = self._process_runtime(plan, nodes, edge_list, pinned, collect=True)
         return _ProcessSession(rt)
 
     # ------------------------------------------------------------ internals
@@ -1542,6 +1547,21 @@ class Engine:
         )
         return pipe, rt
 
+    def _process_runtime(self, plan, nodes, edges, pinned,
+                         collect: Optional[bool] = None) -> ProcessRuntime:
+        """The runtime that executes ``plan`` (or the graph, when no plan
+        was passed), verified before anything forks: a passed plan was
+        verified by :meth:`_resolve`, a graph is verified here."""
+        rt = self._make_process_runtime(
+            nodes, edges, stage_widths=pinned, collect=collect
+        )
+        if plan is None:
+            op_rows, routing = graph_flows(nodes, edges, self.config.cost_priors)
+            self._describe_process(
+                rt, _planned_ops(op_rows), routing, (nodes, edges)
+            ).verify()
+        return rt
+
     def _make_process_runtime(self, nodes, edges, stage_widths=None,
                               collect: Optional[bool] = None) -> ProcessRuntime:
         cfg = self.config
@@ -1593,6 +1613,8 @@ class Engine:
 
     def _describe_process(self, rt: ProcessRuntime, ops, routing,
                           graph) -> PhysicalPlan:
+        from ..columnar.device import host_has_tpu, x64_enabled
+
         profiles = rt.cost_model.profiles
         total = sum(p.load for p in profiles) or 1.0
         stages = [
@@ -1606,6 +1628,7 @@ class Engine:
                 flow=round(prof.flow, 4),
                 load_share=round(prof.load / total, 4),
                 checkpointed=rt._ckpt_enabled(plan.index),
+                device_backend=rt.stage_backend(plan),
             )
             for plan, prof in zip(rt.stage_plans, profiles)
         ]
@@ -1626,6 +1649,11 @@ class Engine:
             "device_batch": rt.device_batch,
             "device_workers": rt.device_workers,
             "device_inflight": rt.device_inflight,
+            # what the device rules (PV413, PV414) check the plan against:
+            # the x64 mode device workers inherit, and whether a jax
+            # backend here opens a TPU (read without bringing one up)
+            "x64": int(x64_enabled()),
+            "tpu_host": int(rt.chip_owners > 0 and host_has_tpu()),
         }
         return PhysicalPlan(
             backend="process", config=self.config, ops=ops, routing=routing,
@@ -1644,8 +1672,8 @@ def _planned_ops(op_rows) -> List[PlannedOp]:
             max_dop = spec.num_partitions
         else:
             max_dop = None
-        schema_width = (
-            spec.schema.width
+        schema = (
+            list(spec.schema.codes)
             if spec.kind == DEVICE and spec.schema is not None else None
         )
         ops.append(
@@ -1657,7 +1685,7 @@ def _planned_ops(op_rows) -> List[PlannedOp]:
                 flow=round(flow, 4),
                 load_share=round(flow * cost / total, 4),
                 max_dop=max_dop,
-                schema_width=schema_width,
+                schema=schema,
             )
         )
     return ops

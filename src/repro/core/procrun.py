@@ -457,19 +457,6 @@ def _worker_main(wid, ingress, reorder, conn, seg_ops, preload=None,
     colout = None  # result-side codec (columnar-armed non-device stages)
     executor = None  # DeviceExecutor (device stages)
     ColumnBlock = None
-    if seg_ops and seg_ops[0].kind == DEVICE:
-        from ..columnar import codec as col
-        from ..columnar.block import ColumnBlock
-        from ..columnar.device import DeviceExecutor
-
-        dbatch, dinflight, dbackend = dev_cfg or (256, 2, "auto")
-        executor = DeviceExecutor(
-            seg_ops[0], batch=dbatch, inflight=dinflight, backend=dbackend
-        )
-    elif columnar:
-        from ..columnar import codec as col
-
-        colout = col.ColumnarCodec()
 
     def publish_block(out) -> None:
         # ordered-egress boundary: the executor synchronised `out` already;
@@ -497,6 +484,23 @@ def _worker_main(wid, ingress, reorder, conn, seg_ops, preload=None,
         return outs
 
     try:
+        if seg_ops and seg_ops[0].kind == DEVICE:
+            from ..columnar import codec as col
+            from ..columnar.block import ColumnBlock
+            from ..columnar.device import DeviceExecutor
+
+            dbatch, dinflight, dbackend = dev_cfg or (256, 2, "auto")
+            # brings the jax backend up; a failure here ends the run with
+            # the error below (never a crash, which would re-fork)
+            executor = DeviceExecutor(
+                seg_ops[0], batch=dbatch, inflight=dinflight, backend=dbackend
+            )
+            if executor.device is not None:
+                conn.send(("device", wid, dict(executor.device)))
+        elif columnar:
+            from ..columnar import codec as col
+
+            colout = col.ColumnarCodec()
         idle = _IDLE_MIN
         while True:
             beat()
@@ -706,6 +710,9 @@ def _worker_main(wid, ingress, reorder, conn, seg_ops, preload=None,
             # elastic resize: the group is quiesced; hand worker-local state
             # back so the supervisor can re-shard it across the new width
             conn.send(("state", wid, pickle.dumps(states, _PICKLE)))
+        if executor is not None and executor.device is not None:
+            conn.send(("device", wid, dict(executor.device,
+                                           dispatches=executor.dispatches)))
         conn.send(("stats", wid, busy, processed))
         conn.close()
     except Exception:
@@ -1389,6 +1396,8 @@ class ProcessRuntime:
             )
         self.device_backend = device_backend
         self.restart_on_crash = restart_on_crash
+        # (stage, worker) -> the latest report of each jax device worker
+        self._devices: Dict[Tuple[int, int], dict] = {}
         if not isinstance(checkpoint_interval, int) or checkpoint_interval < 0:
             raise ValueError(
                 "checkpoint_interval must be an int >= 0 (0 disables), got "
@@ -1486,6 +1495,12 @@ class ProcessRuntime:
         units = max_inflight if max_inflight else 8 * max(num_workers, widest)
         self.max_inflight = min(reorder_size, max(units * self.io_batch, 1))
 
+        #: processes that will bring up a jax backend; on a TPU host each
+        #: one opens the chip, which admits one process (plan rule PV414)
+        self.chip_owners = sum(
+            p.workers for p in self.stage_plans
+            if p.kind == "device" and self.stage_backend(p) == "jax"
+        )
         self.tail_node_names = sorted(tail_nodes)  # plan introspection
         unstaged_routing = [
             name for name, spec in tail_nodes.items()
@@ -1574,6 +1589,15 @@ class ProcessRuntime:
                 plan.max_workers = max(min(cap, spare), plan.workers)
 
     # --------------------------------------------------------------- topology
+    def stage_backend(self, plan: StagePlan) -> Optional[str]:
+        """Resolved kernel backend (``jax``/``numpy``) of a device stage;
+        ``None`` for every other kind."""
+        if plan.kind != "device" or not plan.ops:
+            return None
+        from ..columnar.device import resolve_backend
+
+        return resolve_backend(plan.ops[0].device_backend or self.device_backend)
+
     @property
     def num_stages(self) -> int:
         """How many stages the planner cut (1 = ingress-only plan)."""
@@ -1582,6 +1606,16 @@ class ProcessRuntime:
     def stage_widths(self) -> list[int]:
         """Current per-stage worker-group widths (allocation introspection)."""
         return [p.workers for p in self.stage_plans]
+
+    def device_reports(self) -> List[dict]:
+        """One row per jax device worker: ``stage``, ``worker`` and what its
+        backend reported — ``platform``, device ``kind`` and ``count`` as
+        jax sees them, ``lower_s``/``compile_s``/``compiles``, and (once the
+        worker has exited) ``dispatches``."""
+        return [
+            dict(stage=s, worker=w, **info)
+            for (s, w), info in sorted(self._devices.items())
+        ]
 
     def worker_groups(self) -> list[list[multiprocessing.Process]]:
         """Live worker processes per stage (crash tests / introspection)."""
@@ -1613,13 +1647,10 @@ class ProcessRuntime:
                      preload=None):
         x = self._exchanges[stage]
         plan = self.stage_plans[stage]
-        if plan.kind == "device" and plan.ops:
-            from ..columnar.device import jax_fork_hazard, resolve_backend
+        if self.stage_backend(plan) == "jax":
+            from ..columnar.device import jax_fork_hazard
 
-            backend = resolve_backend(
-                plan.ops[0].device_backend or self.device_backend
-            )
-            if backend == "jax" and jax_fork_hazard():
+            if jax_fork_hazard():
                 # Fail fast: a forked child of a jax-initialized parent
                 # deadlocks on its first computation (inherited XLA
                 # threadpool locks), which would otherwise surface as an
@@ -1720,6 +1751,7 @@ class ProcessRuntime:
             if self.fault_plan is not None else []
         )
         self._eof_seen = False
+        self._devices = {}
         self._monitor = None
         self._traffic = None
         if self.elastic and any(p.resizable for p in self.stage_plans):
@@ -1855,6 +1887,16 @@ class ProcessRuntime:
                 self.dead_letters.append(
                     DeadLetter(info[1], msg[1], serial, op, value, error)
                 )
+        elif kind == "device":  # a device worker's jax backend is up
+            info = self._pinfo[idx]
+            self._devices[(info[1], info[2])] = msg[2]
+            if (
+                msg[2]["platform"] == "tpu" and self.chip_owners > 1
+                and not ignore_errors
+            ):
+                from ..analysis.plancheck import ONE_CHIP_OWNER
+
+                raise RuntimeError(ONE_CHIP_OWNER.format(n=self.chip_owners))
         elif kind == "halted":  # router acked a group-restore halt
             self._halted.add(msg[1])
         elif kind == "stats":
@@ -2077,6 +2119,13 @@ class ProcessRuntime:
                     except (ProcessLookupError, OSError):
                         pass
                 p.join(timeout=5.0)
+                if p.is_alive() and plan.kind == "device":
+                    # a replacement must not open the chip while the old
+                    # owner, unreaped, may still hold it
+                    raise RuntimeError(
+                        f"device worker {info[2]} of stage {stage} did not "
+                        "exit after SIGKILL; not re-forking onto its chip"
+                    )
                 self._procs[i] = None
             try:
                 if self._conns[i] is not None:

@@ -4,6 +4,7 @@ Grid: (batch, q_heads, q_blocks); each program streams key blocks of the
 causal prefix with the online-softmax recurrence, holding one (Bq, Dh) output
 tile + (Bq,) running max/denominator in VMEM. GQA is handled by the KV
 BlockSpec index map (kv head = q head // G) — no KV expansion in HBM.
+Compiled for TPU, head_dim must be a multiple of 128 (the lane width).
 
 VMEM working set per program: q (Bq,Dh) + k/v (Bk,Dh) + scores (Bq,Bk)
 ≈ a few hundred KB for Bq=Bk=128..512 — comfortably under the ~16MB VMEM.
@@ -11,19 +12,22 @@ VMEM working set per program: q (Bq,Dh) + k/v (Bk,Dh) + scores (Bq,Bk)
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..platform import pallas_call
+
 NEG_INF = -1e30
 
 
 def _flash_kernel(
-    q_ref,  # (1, Bq, 1, Dh)
-    k_ref,  # (1, S, 1, Dh)
-    v_ref,  # (1, S, 1, Dh)
-    o_ref,  # (1, Bq, 1, Dh)
+    q_ref,  # (Bq, Dh)
+    k_ref,  # (S, Dh)
+    v_ref,  # (S, Dh)
+    o_ref,  # (Bq, Dh)
     *,
     block_q: int,
     block_k: int,
@@ -31,7 +35,7 @@ def _flash_kernel(
     causal: bool,
 ):
     qi = pl.program_id(2)
-    q = q_ref[0, :, 0, :].astype(jnp.float32)  # (Bq, Dh)
+    q = q_ref[...].astype(jnp.float32)  # (Bq, Dh)
     scale = 1.0 / jnp.sqrt(q.shape[-1]).astype(jnp.float32)
     q = q * scale
 
@@ -43,18 +47,9 @@ def _flash_kernel(
 
     def body(kb, carry):
         m, l, acc = carry
-        # int indices are rejected by pallas load on this jax version; use
-        # size-1 dynamic slices and drop the unit axes after the load.
-        k = pl.load(
-            k_ref,
-            (pl.dslice(0, 1), pl.dslice(kb * block_k, block_k), pl.dslice(0, 1),
-             slice(None)),
-        )[0, :, 0, :].astype(jnp.float32)
-        v = pl.load(
-            v_ref,
-            (pl.dslice(0, 1), pl.dslice(kb * block_k, block_k), pl.dslice(0, 1),
-             slice(None)),
-        )[0, :, 0, :].astype(jnp.float32)
+        rows = pl.ds(kb * block_k, block_k)
+        k = k_ref[rows, :].astype(jnp.float32)
+        v = v_ref[rows, :].astype(jnp.float32)
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # (Bq, Bk)
         if causal:
             k_pos = kb * block_k + jax.lax.broadcasted_iota(
@@ -78,7 +73,7 @@ def _flash_kernel(
     upper = jnp.minimum(upper, seq_len // block_k)
     m, l, acc = jax.lax.fori_loop(0, upper, body, (m, l, acc))
 
-    o_ref[0, :, 0, :] = (acc / jnp.maximum(l, 1e-20)[:, None]).astype(o_ref.dtype)
+    o_ref[...] = (acc / jnp.maximum(l, 1e-20)[:, None]).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -92,7 +87,7 @@ def flash_attention(
     causal: bool = True,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     B, S, H, Dh = q.shape
     Hkv = k.shape[2]
@@ -109,15 +104,19 @@ def flash_attention(
         seq_len=S,
         causal=causal,
     )
-    return pl.pallas_call(
+    # heads are folded into the lane axis, (B, S, H * Dh): a head is then a
+    # (rows, Dh) tile, which TPU tiling admits when Dh is a multiple of 128
+    out = pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, Dh), lambda b, h, i: (b, i, h, 0)),
-            pl.BlockSpec((1, S, 1, Dh), lambda b, h, i: (b, 0, h // G, 0)),
-            pl.BlockSpec((1, S, 1, Dh), lambda b, h, i: (b, 0, h // G, 0)),
+            pl.BlockSpec((None, block_q, Dh), lambda b, h, i: (b, i, h)),
+            pl.BlockSpec((None, S, Dh), lambda b, h, i: (b, 0, h // G)),
+            pl.BlockSpec((None, S, Dh), lambda b, h, i: (b, 0, h // G)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, Dh), lambda b, h, i: (b, i, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, S, H, Dh), q.dtype),
+        out_specs=pl.BlockSpec((None, block_q, Dh), lambda b, h, i: (b, i, h)),
+        out_shape=jax.ShapeDtypeStruct((B, S, H * Dh), q.dtype),
         interpret=interpret,
-    )(q, k, v)
+    )(q.reshape(B, S, H * Dh), k.reshape(B, S, Hkv * Dh),
+      v.reshape(B, S, Hkv * Dh))
+    return out.reshape(B, S, H, Dh)

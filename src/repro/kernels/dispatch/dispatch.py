@@ -14,10 +14,13 @@ kernel with partitions = experts.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from ..platform import pallas_call
 
 
 def _dispatch_kernel(
@@ -69,14 +72,14 @@ def dispatch_pallas(
     *,
     num_partitions: int,
     capacity: int,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     T, W = payloads.shape
     PC = num_partitions * capacity
     kernel = functools.partial(
         _dispatch_kernel, num_partitions=num_partitions, capacity=capacity
     )
-    buffers, counts, dest = pl.pallas_call(
+    buffers, counts, dest = pallas_call(
         kernel,
         out_shape=(
             jax.ShapeDtypeStruct((PC, W), payloads.dtype),

@@ -1,6 +1,8 @@
 """Jit'd public wrapper for the hybrid-queue dispatch kernel."""
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 
 from .dispatch import dispatch_pallas
@@ -14,7 +16,7 @@ def dispatch(
     capacity: int,
     *,
     use_kernel: bool = True,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Route tuples (arrival order = index) into bounded per-partition FIFO
     buffers. Returns (buffers (P,C,W), counts (P,), dest (T,))."""

@@ -1,6 +1,8 @@
 """Jit'd public wrapper for the reorder-commit kernel."""
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 
@@ -14,7 +16,7 @@ def commit(
     payloads: jax.Array,
     *,
     use_kernel: bool = True,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> tuple[ReorderState, jax.Array, jax.Array, jax.Array]:
     """Batched reorder-commit: scatter K completed (serial, payload) pairs into
     the ring and emit the contiguous ready prefix in serial order.

@@ -18,10 +18,13 @@ The whole state lives in VMEM: (S, W) ring + (S,) present + scalar ``next``.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from ..platform import pallas_call
 
 
 def _commit_kernel(
@@ -80,7 +83,8 @@ def _commit_kernel(
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def commit_pallas(buf, present, nxt, serials, payloads, *, interpret=True):
+def commit_pallas(buf, present, nxt, serials, payloads, *,
+                  interpret: Optional[bool] = None):
     """One reorder-commit step. present: (S,) int32; nxt: () int32."""
     S, W = buf.shape
     K = serials.shape[0]
@@ -107,7 +111,7 @@ def commit_pallas(buf, present, nxt, serials, payloads, *, interpret=True):
         pl.BlockSpec((1, 1), lambda: (0, 0)),
         pl.BlockSpec((K, 1), lambda: (0, 0)),
     ]
-    return pl.pallas_call(
+    return pallas_call(
         _commit_kernel,
         out_shape=out_shapes,
         in_specs=specs,

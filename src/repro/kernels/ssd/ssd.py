@@ -14,11 +14,14 @@ Per chunk (all in fp32, on the MXU):
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ..platform import pallas_call
 
 
 def _ssd_kernel(
@@ -87,7 +90,7 @@ def ssd_pallas(
     Cm: jax.Array,  # (B, L, N) fp32
     *,
     chunk: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     B, L, H, P = x.shape
     N = Bm.shape[-1]
@@ -95,7 +98,7 @@ def ssd_pallas(
     nc = L // chunk
     grid = (B, H, nc)
     kernel = functools.partial(_ssd_kernel, num_chunks=nc)
-    y, hT = pl.pallas_call(
+    y, hT = pallas_call(
         kernel,
         grid=grid,
         in_specs=[

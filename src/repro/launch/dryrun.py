@@ -1,9 +1,5 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
-# ^ MUST be the first two lines: jax locks the device count on first init.
-# Everything below may import jax.
 import argparse
+import os
 import json
 import re
 import time
@@ -12,7 +8,7 @@ import traceback
 import jax
 
 from repro.configs import ARCH_IDS, SHAPES, applicable_shapes, get_config
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import force_host_devices, make_production_mesh
 from repro.sharding.context import use_mesh
 from repro.train.optimizer import OptConfig
 from repro.train import train_step as ts
@@ -181,6 +177,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool):
 
 
 def main():
+    force_host_devices()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None, help="one arch id (default: all)")
     ap.add_argument("--shape", default=None, help="one shape (default: applicable)")

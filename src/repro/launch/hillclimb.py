@@ -1,7 +1,3 @@
-import os
-if "--xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Perf hillclimbing driver (EXPERIMENTS.md §Perf).
 
 Measures named config VARIANTS of the three chosen cells and logs
@@ -12,9 +8,11 @@ hypothesis -> change -> before/after on the dominant roofline term.
 import argparse
 import dataclasses
 import json
+import os
 
 from repro.configs import SHAPES, get_config
 from repro.launch import roofline as rl
+from repro.launch.mesh import force_host_devices
 
 # Registry of (arch, shape, [(variant_name, config_transform), ...])
 def _v(name, **kw):
@@ -82,6 +80,7 @@ def measure(arch, shape_name, cfg, multi_pod=False):
 
 
 def main():
+    force_host_devices()
     ap = argparse.ArgumentParser()
     ap.add_argument("--cell", required=True, choices=list(CELLS))
     ap.add_argument("--variant", default=None)

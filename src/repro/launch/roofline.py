@@ -1,7 +1,3 @@
-import os
-if "--xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Loop-corrected roofline analysis (EXPERIMENTS.md §Roofline).
 
 XLA's cost analysis counts a while-loop body ONCE regardless of trip count
@@ -19,6 +15,7 @@ Collective bytes get the same correction (bodies parsed separately).
 """
 import argparse
 import json
+import os
 
 import jax
 import jax.numpy as jnp
@@ -33,7 +30,7 @@ from repro.launch.dryrun import (
     collective_bytes_per_device,
     lower_cell,
 )
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import force_host_devices, make_production_mesh
 from repro.models import transformer
 from repro.models.common import (
     ModelConfig,
@@ -203,6 +200,7 @@ def corrected_record(
 
 
 def main():
+    force_host_devices()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

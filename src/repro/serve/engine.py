@@ -124,6 +124,14 @@ class OrderedServingEngine:
         )
         return serial
 
+    def prefill_logits(self, prompt: np.ndarray) -> jax.Array:
+        """Last-position logits of this engine's prefill step for ``prompt``
+        (a request's first token is their argmax); no slot state changes."""
+        logits, _ = self._prefill1(
+            self.params, np.asarray(prompt, np.int32)[None, :]
+        )
+        return logits[0]
+
     def _emit(self, completion: Completion) -> None:
         self.completions.append(completion)
         self.stats["emitted"] += 1

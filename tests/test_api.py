@@ -247,7 +247,7 @@ def _device_chain():
 
     return [
         OpSpec("pre", "stateless", _ident, cost_us=3.0),
-        device_op("affine", "affine", Schema.of("i8", scalar=True),
+        device_op("affine", "affine", Schema.of("i4", scalar=True),
                   params={"a": 3, "b": 1}, cost_us=20.0),
         OpSpec("post", "stateless", _ident, cost_us=3.0),
     ]
@@ -267,8 +267,8 @@ def test_explain_golden_device_chain():
     dev = [s for s in plan.stages if s.kind == "device"]
     assert len(dev) == 1 and dev[0].workers == dev[0].max_workers == 1
     assert dev[0].checkpointed
-    # the device op row carries its declared schema width
-    assert [op.schema_width for op in plan.ops] == [None, 1, None]
+    # the device op row carries its declared schema
+    assert [op.schema for op in plan.ops] == [None, ["i4"], None]
 
 
 def test_device_plan_dict_round_trip_preserves_verification():
@@ -292,9 +292,82 @@ def test_device_plan_dict_round_trip_preserves_verification():
     assert "PV411" in rules2
     # strip the schema claim -> PV412
     clone3 = PhysicalPlan.from_dict(plan.to_dict())
-    clone3.ops[1].schema_width = None
+    clone3.ops[1].schema = None
     rules3 = {v.rule for v in clone3.verify(raise_on_violation=False)}
     assert "PV412" in rules3
+
+
+def _jax_device_ops(code="i4", stages=1):
+    from repro.columnar import Schema, device_op
+
+    return [OpSpec("pre", "stateless", _ident, cost_us=3.0)] + [
+        device_op(f"dev{i}", "affine", Schema.of(code, scalar=True),
+                  params={"a": 3, "b": 1}, backend="jax")
+        for i in range(stages)
+    ]
+
+
+def test_wide_jax_device_schema_is_refused_not_narrowed():
+    """An i8 device schema on the jax backend would compute in 32 bits (x64
+    off) while the NumPy reference computes in 64: refused at plan time
+    (PV413) and again by the executor, instead of narrowed in silence."""
+    pytest.importorskip("jax")
+    from repro.columnar import DeviceExecutor, x64_enabled
+    from repro.core import PlanVerificationError
+
+    assert not x64_enabled()
+    eng = Engine(EngineConfig(
+        backend="process", num_workers=2,
+        process=ProcessOptions(worker_budget=4, columnar=True),
+    ))
+    with pytest.raises(PlanVerificationError) as exc:
+        eng.plan(_jax_device_ops("i8"))
+    assert [v.rule for v in exc.value.violations] == ["PV413"]
+    with pytest.raises(PlanVerificationError):
+        eng.run(_jax_device_ops("f8"), [1, 2, 3])  # graphs verify too
+    plan = eng.plan(_jax_device_ops("i4"))
+    assert plan.stages[1].device_backend == "jax"
+    clone = PhysicalPlan.from_dict(plan.to_dict())
+    clone.ops[1].schema = ["i8"]
+    assert {v.rule for v in clone.verify(raise_on_violation=False)} == {"PV413"}
+    clone.ring["x64"] = 1  # workers running jax with x64 may declare i8
+    assert clone.verify(raise_on_violation=False) == []
+    with pytest.raises(ValueError, match="64-bit"):
+        DeviceExecutor(_jax_device_ops("i8")[1])
+
+
+@pytest.mark.parametrize("stages,device_workers", [(2, 1), (1, 2)])
+def test_second_chip_owner_on_tpu_host_fails_fast(monkeypatch, stages,
+                                                  device_workers):
+    """Two jax device stages, or device_workers=2, would put two processes
+    on one TPU: refused at plan time on a TPU host (the platform check is
+    steered here), and by the supervisor when a device worker reports a
+    TPU under a plan that was never verified."""
+    pytest.importorskip("jax")
+    import repro.columnar.device as device
+    from repro.core import PlanVerificationError, ProcessRuntime
+
+    ops = _jax_device_ops(stages=stages)
+    eng = Engine(EngineConfig(
+        backend="process", num_workers=2,
+        process=ProcessOptions(worker_budget=4, columnar=True,
+                               device_workers=device_workers),
+    ))
+    assert eng.plan(ops).verify(raise_on_violation=False) == []  # not a TPU
+    monkeypatch.setattr(device, "host_has_tpu", lambda: True)
+    with pytest.raises(PlanVerificationError, match="one chip-owning") as exc:
+        eng.plan(ops)
+    assert [v.rule for v in exc.value.violations] == ["PV414"]
+    with pytest.raises(PlanVerificationError, match="one chip-owning"):
+        eng.open(ops)
+
+    rt = ProcessRuntime.from_chain(ops, num_workers=2,
+                                   device_workers=device_workers)
+    assert rt.chip_owners == 2
+    rt._pinfo = [("worker", 1, 0)]
+    with pytest.raises(RuntimeError, match="one chip-owning"):
+        rt._on_message(0, ("device", 0, {"platform": "tpu", "kind": "TPU v5e",
+                                         "count": 1}))
 
 
 # ------------------------------------------------------- plan dict round-trip

@@ -208,7 +208,7 @@ def test_columnar_egress_equals_pickle_egress(batch_size):
 def _device_chain(backend: str, kernel: str = "affine"):
     return [
         OpSpec("widen2", "stateless", _pair, cost_us=1.0),
-        device_op("dev", kernel, Schema.of("i8", "i8"),
+        device_op("dev", kernel, Schema.of("i4", "i4"),
                   params={"a": 3, "b": -1}, backend=backend, cost_us=4.0),
         OpSpec("fold", "stateless", _fold, cost_us=1.0),
     ]
@@ -227,7 +227,7 @@ def _device_reference(source):
     for v in source:
         (t,) = _pair(v)
         (r,) = ref_apply(t, "affine", (("a", 3), ("b", -1)),
-                         Schema.of("i8", "i8"))
+                         Schema.of("i4", "i4"))
         out.extend(_fold(r))
     return out
 
@@ -345,7 +345,7 @@ jax.random.PRNGKey(0)  # initializes the CPU client: the hazard
 assert jax_fork_hazard()
 from repro.core import Engine, EngineConfig, ProcessOptions
 from repro.columnar import Schema, device_op
-ops = [device_op("dev", "affine", Schema.of("i8", scalar=True),
+ops = [device_op("dev", "affine", Schema.of("i4", scalar=True),
                  params={"a": 2, "b": 1}, backend="jax")]
 eng = Engine(EngineConfig(
     backend="process", num_workers=1, batch_size=4, collect_outputs=True,

@@ -42,10 +42,7 @@ with mesh, use_mesh(mesh):
     compiled = jax.jit(step, in_shardings=ins, out_shardings=outs,
                        donate_argnums=(0, 1)).lower(*args).compile()
 results["train_compiles"] = True
-ca = compiled.cost_analysis()
-if isinstance(ca, (list, tuple)):  # older jax: one entry per device program
-    ca = ca[0] if ca else {}
-results["train_flops"] = ca.get("flops", 0)
+results["train_flops"] = compiled.cost_analysis().get("flops", 0)
 
 # --- multi-pod test mesh (2,2,2): pod axis must shard
 cfg2 = smoke_config("qwen2-moe-a2.7b")
@@ -78,25 +75,15 @@ results["loss_single"] = float(loss_single)
 
 # --- int8 error-feedback gradient psum over the pod axis (shard_map)
 from repro.train.grad_compression import compress_allreduce_leaf
-try:
-    shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map
 g = jnp.arange(16.0).reshape(2, 8) * 0.01  # (pod-sharded dim, payload)
 err = jnp.zeros((2, 8))
 def fn(gl, el):
     s, e = compress_allreduce_leaf(gl[0], el[0], "pod")
     return s[None], e[None]
-import inspect
-_sm_kw = (
-    {"check_vma": False}
-    if "check_vma" in inspect.signature(shard_map).parameters
-    else {"check_rep": False}  # pre-0.5 jax spelling
-)
 with mesh2:
-    summed, new_err = shard_map(
+    summed, new_err = jax.shard_map(
         fn, mesh=mesh2, in_specs=(P("pod", None), P("pod", None)),
-        out_specs=(P("pod", None), P("pod", None)), **_sm_kw,
+        out_specs=(P("pod", None), P("pod", None)), check_vma=False,
     )(g, err)
 true_sum = g.sum(axis=0)
 rel = float(jnp.linalg.norm(summed[0] - true_sum) / (jnp.linalg.norm(true_sum)))

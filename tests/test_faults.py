@@ -41,9 +41,13 @@ REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 # ---------------------------------------------------------------- helpers
-def _shm_segments():
+def _shm_segments(pid=None):
+    """Runtime segments created by this process (or ``pid``): their names
+    carry the creator's pid, so test workers running side by side never
+    see each other's live segments."""
+    prefix = f"repro_{pid or os.getpid()}_"
     try:
-        return {f for f in os.listdir("/dev/shm") if f.startswith("repro_")}
+        return {f for f in os.listdir("/dev/shm") if f.startswith(prefix)}
     except FileNotFoundError:  # non-Linux: nothing to check
         return set()
 
@@ -473,6 +477,7 @@ def test_sigterm_mid_run_tears_down_without_shm_leak():
             proc.wait(timeout=10)
     assert rc == 143, f"expected graceful SystemExit(143), got {rc}"
     assert _shm_segments() == before
+    assert _shm_segments(proc.pid) == set()  # the child's own segments
 
 
 # --------------------------------------------------- spill-deadline context

@@ -1,4 +1,4 @@
-"""Per-kernel tests: Pallas (interpret=True) vs pure-jnp oracle, sweeping
+"""Per-kernel tests: Pallas (interpreted off-TPU) vs pure-jnp oracle, sweeping
 shapes and dtypes (deliverable c)."""
 import numpy as np
 import pytest

@@ -44,9 +44,13 @@ def _oracle(vals, drop_mod=3):
     return out
 
 
-def _shm_segments():
+def _shm_segments(pid=None):
+    """Runtime segments created by this process (or ``pid``): their names
+    carry the creator's pid, so test workers running side by side never
+    see each other's live segments."""
+    prefix = f"repro_{pid or os.getpid()}_"
     try:
-        return {f for f in os.listdir("/dev/shm") if f.startswith("repro_")}
+        return {f for f in os.listdir("/dev/shm") if f.startswith(prefix)}
     except FileNotFoundError:  # non-Linux: nothing to check
         return set()
 
