@@ -1,0 +1,7 @@
+"""Mean queued slots of the exchange ring in front of the device stage,
+from ``Session.stats()["backlog_slots"]`` sampled every 100 ms over the
+window."""
+
+
+def read(ctx):
+    return ctx["ring_backlog"]("device")
