@@ -185,6 +185,10 @@ class ColumnBlock:
     columns: List[np.ndarray]
     serials: np.ndarray
     marks: List[Tuple[int, Any]] = field(default_factory=list)
+    #: ``time.perf_counter_ns()`` when a device stage took the unit in (0
+    #: elsewhere); the stage worker charges the time since to its hold
+    #: counter when it publishes the unit
+    held_since: int = field(default=0, compare=False, repr=False)
 
     # ----------------------------------------------------------- builders
     @classmethod

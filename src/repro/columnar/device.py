@@ -43,6 +43,7 @@ from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ..core import trace
 from ..core.operators import DEVICE, OpSpec
 from .block import ColumnBlock, Schema
 
@@ -331,7 +332,14 @@ class DeviceExecutor:
     backend came up: ``platform``, ``kind`` and device ``count`` as jax
     reports them, with the seconds spent tracing and lowering
     (``lower_s``) and compiling (``compile_s``, what the persistent cache
-    saves) over ``compiles`` shapes; ``None`` on the NumPy backend."""
+    saves) over ``compiles`` shapes; ``None`` on the NumPy backend.
+
+    Each submitted block is stamped (``held_since``) and the stamp rides
+    its unit back out, so the caller can tell how long a unit was held.  A
+    jax executor arms :func:`repro.core.trace.span` in its process: each
+    dispatch records ``stream.device.dispatch`` (zero-padded columns up,
+    and the launch) and each synchronisation ``stream.device.sync`` (the
+    wait and the columns back down) where a profiler runs."""
 
     def __init__(
         self,
@@ -356,6 +364,7 @@ class DeviceExecutor:
             self.device = self._bring_up(spec.name)
             self._fn = jax.jit(self._fn)
             self._executable(self.batch)
+            trace.arm(jax.profiler.TraceAnnotation)
         self._pending: List[ColumnBlock] = []
         self._pending_rows = 0
         self._inflight: Deque[Tuple[Any, list, int]] = deque()
@@ -425,6 +434,7 @@ class DeviceExecutor:
     def submit(self, block: ColumnBlock) -> List[ColumnBlock]:
         """Absorb one unit's block; returns any units whose batches
         completed (possibly none, never blocks unless the window is full)."""
+        block.held_since = time.perf_counter_ns()
         if self._pending and self._pending_rows + len(block) > self.batch:
             self._dispatch()
         self._pending.append(block)
@@ -449,31 +459,35 @@ class DeviceExecutor:
     def _dispatch(self) -> None:
         blocks = self._pending
         n = self._pending_rows
-        units = [(b.serials, b.marks) for b in blocks]
+        units = [(b.serials, b.marks, b.held_since) for b in blocks]
         self._pending = []
         self._pending_rows = 0
-        if self.backend == "jax":
-            rows = -(-n // self.batch) * self.batch
-            cols = []
-            for i, dt in enumerate(self.schema.dtypes):
-                # fresh buffer, zero-padded to the compiled shape: safe to
-                # alias zero-copy, the host never mutates it after dispatch
-                col = np.zeros(rows, dt)
-                np.concatenate([b.columns[i] for b in blocks], out=col[:n])
-                cols.append(col)
-            outs = self._executable(rows)(*cols)
-        else:
-            outs = self._fn(*ColumnBlock.concat(blocks).columns)
+        with trace.span(trace.DEVICE_DISPATCH):
+            if self.backend == "jax":
+                rows = -(-n // self.batch) * self.batch
+                cols = []
+                for i, dt in enumerate(self.schema.dtypes):
+                    # fresh buffer, zero-padded to the compiled shape: safe
+                    # to alias zero-copy, the host never mutates it after
+                    # dispatch
+                    col = np.zeros(rows, dt)
+                    np.concatenate([b.columns[i] for b in blocks],
+                                   out=col[:n])
+                    cols.append(col)
+                outs = self._executable(rows)(*cols)
+            else:
+                outs = self._fn(*ColumnBlock.concat(blocks).columns)
         self.dispatches += 1
         self._inflight.append((outs, units, n))
 
     def _pop(self) -> List[ColumnBlock]:
         outs, units, n = self._inflight.popleft()
-        if self.backend == "jax":
-            import jax
+        with trace.span(trace.DEVICE_SYNC):
+            if self.backend == "jax":
+                import jax
 
-            outs = jax.block_until_ready(outs)
-        cols = [np.asarray(o)[:n] for o in outs]
+                outs = jax.block_until_ready(outs)
+            cols = [np.asarray(o)[:n] for o in outs]
         for c, dt in zip(cols, self.schema.dtypes):
             if c.dtype != dt:
                 raise TypeError(
@@ -482,7 +496,7 @@ class DeviceExecutor:
                 )
         blocks: List[ColumnBlock] = []
         off = 0
-        for serials, marks in units:
+        for serials, marks, held_since in units:
             n = len(serials)
             blocks.append(
                 ColumnBlock(
@@ -490,6 +504,7 @@ class DeviceExecutor:
                     [c[off : off + n] for c in cols],
                     serials,
                     list(marks),
+                    held_since,
                 )
             )
             off += n

@@ -1067,7 +1067,10 @@ class Session:
 
     def stats(self) -> dict:
         """Live counters: tuples pushed/egressed plus backend-specific
-        occupancy (scheduler snapshot or stage widths/backlog)."""
+        occupancy (scheduler snapshot or stage widths/backlog).  The
+        process backend adds where its processes' time went:
+        ``stage_counters``, ``router_counters`` and ``supervisor_counters``
+        (see :meth:`repro.core.procrun.ProcessRuntime.counters`)."""
         raise NotImplementedError
 
     def offer_load(self, signals: dict) -> None:
@@ -1333,6 +1336,7 @@ class _ProcessSession(Session):
             "resize_stalls": list(rt.resize_stalls),
             "resize_aborts": rt.resize_aborts,
             "resize_reverts": rt.resize_reverts,
+            **rt.counters(),
         }
 
     def close(self, drain_timeout: float = 60.0) -> RunReport:

@@ -102,6 +102,13 @@ elementwise kernels make results independent of batch regrouping).  A
 device worker also flushes partial batches on barriers, EOF, and upstream
 stalls, so an idle pipeline can never wedge on rows parked below the
 batch threshold.
+
+**Counters.** Each worker and router charges every loop pass to busy,
+wait or blocked nanoseconds, stored once per pass in its ring header
+(:attr:`~.shm.ShmSpscRing.COUNTERS`, :attr:`~.shm.ShmReorderRing.COUNTERS`)
+and reloaded by a re-forked replacement, so they stay monotone; the
+supervisor times its own crank in-process.  :meth:`ProcessRuntime.counters`
+reads them all, and ``RunReport.worker_busy_frac`` is computed from them.
 """
 from __future__ import annotations
 
@@ -132,7 +139,7 @@ from .faults import (
 from .operators import DEVICE, OpSpec, PARTITIONED, STATEFUL, STATELESS, _Marker
 from .pipeline import GraphPipeline, Merge, NodeSpec, Split, percentile_latencies
 from .runtime import RunReport
-from . import shm
+from . import shm, trace
 
 _PICKLE = pickle.HIGHEST_PROTOCOL
 
@@ -388,12 +395,47 @@ def _apply_segment_safe(ops, states, value, policies):
     return vals, None
 
 
+# indices into a ring's counter mirror (shm.ShmSpscRing.COUNTERS; a
+# router's shm.ShmReorderRing.COUNTERS are the first three)
+_BUSY, _WAIT, _BLOCKED, _ROWS, _HOLD_NS, _HOLD_UNITS = range(6)
+
+
+class _PassClock:
+    """Charges a stage process's time to busy, wait or blocked, one loop
+    pass at a time, into a ring's counter mirror ``ctr`` (reloaded from
+    shared memory at start, so the counters stay monotone across a
+    re-fork).  :meth:`end_pass` gives the time since the previous pass to
+    one kind, less what :meth:`charge` gave to another kind inside it (a
+    FULL spin, an idle flush), and stores the mirror: one store per pass,
+    never per row."""
+
+    __slots__ = ("ctr", "store", "_mark", "_inner")
+
+    def __init__(self, ctr: list, store: Callable[[], None]):
+        self.ctr = ctr
+        self.store = store
+        self._mark = time.perf_counter_ns()
+        self._inner = 0
+
+    def charge(self, kind: int, ns: int) -> None:
+        self.ctr[kind] += ns
+        self._inner += ns
+
+    def end_pass(self, kind: int) -> None:
+        now = time.perf_counter_ns()
+        self.ctr[kind] += now - self._mark - self._inner
+        self._mark = now
+        self._inner = 0
+        self.store()
+
+
 def _publish(reorder, conn, serial, tag, data, span, beat=None,
-             spill_delay=None) -> None:
+             spill_delay=None, clock=None) -> None:
     """Publish one result slot, spilling oversized bodies via the pipe; spins
     (with teardown escape) while the reorder window is full.  ``beat`` keeps
     the worker's heartbeat live through a long FULL spin (backpressure is
-    not a stall); ``spill_delay`` is the fault-injection hook."""
+    not a stall) and ``clock`` charges the spin to blocked, stored on each
+    turn; ``spill_delay`` is the fault-injection hook."""
     if len(data) > reorder.payload_bytes:
         if spill_delay:
             spec = spill_delay.pop(serial, None)
@@ -402,12 +444,19 @@ def _publish(reorder, conn, serial, tag, data, span, beat=None,
         conn.send(("spill", serial, tag, data))  # body via pipe, before the tag
         tag, data = shm.TAG_SPILL, b""
     spin = _IDLE_MIN
+    t = None
     while True:
         st = reorder.try_publish(serial, tag, data, span)
-        if st != shm.ShmReorderRing.FULL:
+        if st != shm.ShmReorderRing.FULL or reorder.stopped():
+            if t is not None and clock is not None:
+                clock.charge(_BLOCKED, time.perf_counter_ns() - t)
             return
-        if reorder.stopped():
-            return
+        if clock is not None:
+            now = time.perf_counter_ns()
+            if t is not None:
+                clock.charge(_BLOCKED, now - t)
+                clock.store()
+            t = now
         if beat is not None:
             beat()
         time.sleep(spin)
@@ -441,8 +490,6 @@ def _worker_main(wid, ingress, reorder, conn, seg_ops, preload=None,
     batch-executor path (see the module docstring)."""
     ingress.sync_consumer()  # crash replacement: resume at the shared cursor
     states = preload if preload is not None else _init_states(seg_ops)
-    busy = 0.0
-    processed = 0
     code = 0
     beat = ingress.beat
     last_seen = 0  # highest serial applied to state (dedup stages only)
@@ -457,13 +504,21 @@ def _worker_main(wid, ingress, reorder, conn, seg_ops, preload=None,
     colout = None  # result-side codec (columnar-armed non-device stages)
     executor = None  # DeviceExecutor (device stages)
     ColumnBlock = None
+    clock = None  # _PassClock over this worker's ingress-ring counters
+    kind = _WAIT  # what the current loop pass is charged to
+    waiting = None  # the open stream.device.wait span (device stages)
 
     def publish_block(out) -> None:
         # ordered-egress boundary: the executor synchronised `out` already;
         # publish rides the generic span/spill path under the block's head
-        if not reorder.published(out.head_serial):
-            _publish(reorder, conn, out.head_serial, shm.TAG_COLBLOCK,
-                     col.encode_block(out), len(out), beat, spill_delay)
+        with trace.span(trace.DEVICE_PUBLISH):
+            if not reorder.published(out.head_serial):
+                _publish(reorder, conn, out.head_serial, shm.TAG_COLBLOCK,
+                         col.encode_block(out), len(out), beat, spill_delay,
+                         clock)
+        ctr = clock.ctr
+        ctr[_HOLD_NS] += time.perf_counter_ns() - out.held_since
+        ctr[_HOLD_UNITS] += 1
 
     def apply_one(serial, v):
         if op_err is not None and serial in op_err:
@@ -502,7 +557,11 @@ def _worker_main(wid, ingress, reorder, conn, seg_ops, preload=None,
 
             colout = col.ColumnarCodec()
         idle = _IDLE_MIN
+        # the counters start here, once a device backend is up
+        clock = _PassClock(ingress.counters, ingress.store_counters)
+        ctr = clock.ctr
         while True:
+            clock.end_pass(kind)
             beat()
             # Sample the close flags BEFORE peeking: the producer publishes
             # its last records before setting closed, and stores are ordered,
@@ -513,6 +572,10 @@ def _worker_main(wid, ingress, reorder, conn, seg_ops, preload=None,
             closing = ingress.closed() or reorder.stopped()
             rec = ingress.peek()
             if rec is None:
+                kind = _WAIT
+                if waiting is None and executor is not None:
+                    waiting = trace.span(trace.DEVICE_WAIT)
+                    waiting.__enter__()
                 if (
                     executor is not None
                     and (executor.pending_rows or executor.inflight)
@@ -522,14 +585,22 @@ def _worker_main(wid, ingress, reorder, conn, seg_ops, preload=None,
                     # below the batch threshold — the inflight window could be
                     # wedged on exactly those serials.  Elementwise kernels
                     # make the partial-batch flush result-identical.
+                    t = time.perf_counter_ns()
+                    blocked = ctr[_BLOCKED]
                     for out in executor.flush():
                         publish_block(out)
+                    clock.charge(_BUSY, time.perf_counter_ns() - t
+                                 - (ctr[_BLOCKED] - blocked))
                 if closing:
                     break
                 time.sleep(idle)
                 idle = min(idle * 2, _IDLE_MAX)
                 continue
             idle = _IDLE_MIN
+            kind = _BUSY
+            if waiting is not None:
+                waiting.__exit__(None, None, None)
+                waiting = None
             serial, tag, data, nslots = rec
             if tag == shm.TAG_BARRIER:
                 if executor is not None:
@@ -548,7 +619,6 @@ def _worker_main(wid, ingress, reorder, conn, seg_ops, preload=None,
                            pickle.dumps(states, _PICKLE)))
                 ingress.advance(nslots)
                 continue
-            t_begin = time.perf_counter()
             if tag == shm.TAG_KUNIT:
                 serials, values, marks = pickle.loads(data)
                 if dedup and serials and serials[0] <= last_seen:
@@ -573,8 +643,7 @@ def _worker_main(wid, ingress, reorder, conn, seg_ops, preload=None,
                     results.append((serials[i], apply_one(serials[i], v), m))
                 if dedup:
                     last_seen = serials[-1]
-                processed += len(values)
-                busy += time.perf_counter() - t_begin
+                ctr[_ROWS] += len(values)
                 # Per-SERIAL results so the downstream drain restores the
                 # cross-worker interleave — but published as ONE batched
                 # TAG_KBUNDLES slot at the unit's first serial (the drainer
@@ -597,40 +666,44 @@ def _worker_main(wid, ingress, reorder, conn, seg_ops, preload=None,
                 if len(entries) > 1 and len(blob) <= reorder.payload_bytes:
                     if not reorder.published(entries[0][0]):
                         _publish(reorder, conn, entries[0][0],
-                                 shm.TAG_KBUNDLES, blob, 1, beat, spill_delay)
+                                 shm.TAG_KBUNDLES, blob, 1, beat, spill_delay,
+                                 clock)
                 else:
                     for s, btag, bdata in entries:
                         if not reorder.published(s):
                             _publish(reorder, conn, s, btag, bdata, 1,
-                                     beat, spill_delay)
+                                     beat, spill_delay, clock)
             else:  # TAG_UNIT/TAG_COLBLOCK: contiguous span [serial, serial+len)
-                block = None
-                if tag == shm.TAG_COLBLOCK:
-                    if col is None:  # upstream device stage, columnar off
-                        from ..columnar import codec as col
-                    block = col.decode_block(data)
-                    values, marks = None, block.marks
-                else:
-                    values, marks = pickle.loads(data)
+                block = blk = None
+                t_begin = time.perf_counter()
+                # a no-op context outside the device worker (trace.span)
+                with trace.span(trace.DEVICE_DECODE):
+                    if tag == shm.TAG_COLBLOCK:
+                        if col is None:  # upstream device stage, columnar off
+                            from ..columnar import codec as col
+                        block = col.decode_block(data)
+                        values, marks = None, block.marks
+                    else:
+                        values, marks = pickle.loads(data)
+                    if executor is not None:
+                        blk = block
+                        if blk is None:
+                            blk = ColumnBlock.from_values(
+                                values, head_serial=serial, marks=marks,
+                                schema=executor.schema,
+                            )
+                        elif blk.schema != executor.schema:
+                            blk = ColumnBlock.from_values(
+                                blk.to_values(), head_serial=serial,
+                                marks=marks, schema=executor.schema,
+                            )
                 if executor is not None:
-                    blk = block
-                    if blk is None:
-                        blk = ColumnBlock.from_values(
-                            values, head_serial=serial, marks=marks,
-                            schema=executor.schema,
-                        )
-                    elif blk.schema != executor.schema:
-                        blk = ColumnBlock.from_values(
-                            blk.to_values(), head_serial=serial, marks=marks,
-                            schema=executor.schema,
-                        )
                     if blk is not None:
                         for _, m in blk.marks:
                             if not m.begin:
                                 m.begin = t_begin
                         ready = executor.submit(blk)
-                        processed += len(blk)
-                        busy += time.perf_counter() - t_begin
+                        ctr[_ROWS] += len(blk)
                         # Commit BEFORE publish: the device batch spans
                         # ingress units, so this worker can never be replayed
                         # by per-worker re-fork — device stages recover via
@@ -670,8 +743,7 @@ def _worker_main(wid, ingress, reorder, conn, seg_ops, preload=None,
                             dropped.append(m)
                 if dedup:
                     last_seen = serial + len(values) - 1
-                processed += len(values)
-                busy += time.perf_counter() - t_begin
+                ctr[_ROWS] += len(values)
                 if not reorder.published(serial):
                     enc = None
                     if colout is not None and not dropped and all(
@@ -686,14 +758,14 @@ def _worker_main(wid, ingress, reorder, conn, seg_ops, preload=None,
                         )
                     if enc is not None:
                         _publish(reorder, conn, serial, shm.TAG_COLBLOCK,
-                                 enc[0], len(values), beat, spill_delay)
+                                 enc[0], len(values), beat, spill_delay, clock)
                     else:
                         bdata = pickle.dumps(
                             (bundles, out_marks, dropped), _PICKLE
                         )
                         _publish(
                             reorder, conn, serial, shm.TAG_BUNDLES, bdata,
-                            len(values), beat, spill_delay,
+                            len(values), beat, spill_delay, clock,
                         )
             if dead:
                 conn.send(("dead", wid, dead))
@@ -706,6 +778,10 @@ def _worker_main(wid, ingress, reorder, conn, seg_ops, preload=None,
         except Exception:
             pass
     try:
+        if waiting is not None:
+            waiting.__exit__(None, None, None)
+        if clock is not None:
+            clock.end_pass(kind)
         if code == 0 and ingress.handoff_requested():
             # elastic resize: the group is quiesced; hand worker-local state
             # back so the supervisor can re-shard it across the new width
@@ -713,7 +789,6 @@ def _worker_main(wid, ingress, reorder, conn, seg_ops, preload=None,
         if executor is not None and executor.device is not None:
             conn.send(("device", wid, dict(executor.device,
                                            dispatches=executor.dispatches)))
-        conn.send(("stats", wid, busy, processed))
         conn.close()
     except Exception:
         pass
@@ -787,6 +862,7 @@ class _Dispatcher:
             collections.deque() for _ in range(exchange.consumers)
         ]
         self._queued = 0
+        self.seal_ns = 0  # nanoseconds spent sealing units (per unit, not row)
 
     def set_workers(self, w: int) -> None:
         """Elastic resize: point routing at the new active width.  Only legal
@@ -892,29 +968,31 @@ class _Dispatcher:
         serials, vals, marks = self._acc[w]
         if not vals:
             return
+        t = time.perf_counter_ns()
         self._acc[w] = ([], [], [])
         data = pickle.dumps((serials, vals, marks), _PICKLE)
         self._outq[w].append((serials[0], shm.TAG_KUNIT, data))
         self._queued += 1
+        self.seal_ns += time.perf_counter_ns() - t
 
     def _seal_contiguous(self) -> None:
         vals, marks = self._vals, self._marks
         if not vals:
             return
+        t = time.perf_counter_ns()
         self._vals, self._marks = [], []
         head = self._head_serial
         self._head_serial = self.next_serial
+        enc = None
         if self._codec is not None:
             enc = self._codec.try_encode_unit(vals, marks, head)
-            if enc is not None:
-                self._outq[next(self._rr)].append(
-                    (head, shm.TAG_COLBLOCK, enc[0])
-                )
-                self._queued += 1
-                return
-        data = pickle.dumps((vals, marks), _PICKLE)
-        self._outq[next(self._rr)].append((head, shm.TAG_UNIT, data))
+        if enc is not None:
+            unit = (head, shm.TAG_COLBLOCK, enc[0])
+        else:
+            unit = (head, shm.TAG_UNIT, pickle.dumps((vals, marks), _PICKLE))
+        self._outq[next(self._rr)].append(unit)
         self._queued += 1
+        self.seal_ns += time.perf_counter_ns() - t
 
     def add_block(self, block) -> bool:
         """Columnar pass-through: route a whole decoded block as one unit,
@@ -1116,13 +1194,17 @@ def _router_main(ridx, upstream, exchange, conn, plan, io_batch, max_inflight,
         f"stage {ridx} router, ingress backlog "
         f"{exchange.backlog_slots()} slots"
     )
-    busy = 0.0
     code = 0
+    # busy draining and routing, waiting on an empty upstream, or blocked
+    # while the downstream stage takes no more (gate closed, rings full)
+    clock = _PassClock(upstream.counters, upstream.store_counters)
+    kind = _WAIT
     try:
         idle = _IDLE_MIN
         eof = False
         conn_at = 0.0
         while not eof:
+            clock.end_pass(kind)
             if upstream.stopped():
                 break
             now = time.monotonic()
@@ -1134,6 +1216,7 @@ def _router_main(ridx, upstream, exchange, conn, plan, io_batch, max_inflight,
                 pump_conn()
             service_ctrl()
             if disp.paused:
+                kind = _BLOCKED
                 if disp.pump():
                     continue  # keep moving sealed units into the rings
                 if not state["acked"] and not disp.pending():
@@ -1142,8 +1225,8 @@ def _router_main(ridx, upstream, exchange, conn, plan, io_batch, max_inflight,
                 time.sleep(1e-3)
                 continue
             drained = 0
-            if disp.ready():
-                t0 = time.perf_counter()
+            ready = disp.ready()
+            if ready:
                 for _ in range(64):  # batch the drain: one pump per sweep
                     got = upstream.read_ahead()
                     if got is None:
@@ -1158,8 +1241,6 @@ def _router_main(ridx, upstream, exchange, conn, plan, io_batch, max_inflight,
                         )
                     _route_result(disp, conn, tag, data)
                     drained += 1
-                if drained:
-                    busy += time.perf_counter() - t0
             # commit policy: once read-ahead spans half the upstream window
             # (publishers would soon stall on FULL), flush the partials and
             # take the next safe commit point — everything read durably in
@@ -1176,6 +1257,7 @@ def _router_main(ridx, upstream, exchange, conn, plan, io_batch, max_inflight,
                 committed = upstream.read_pos()
                 want_commit = False
             if drained or eof:
+                kind = _BUSY
                 idle = _IDLE_MIN
                 disp.pump()
                 continue
@@ -1193,8 +1275,10 @@ def _router_main(ridx, upstream, exchange, conn, plan, io_batch, max_inflight,
                     committed = upstream.read_pos()
                     want_commit = False
             if moved:
+                kind = _BUSY
                 idle = _IDLE_MIN
             else:
+                kind = _WAIT if ready else _BLOCKED
                 time.sleep(idle)
                 idle = min(idle * 2, _IDLE_MAX)
         if eof:
@@ -1205,12 +1289,15 @@ def _router_main(ridx, upstream, exchange, conn, plan, io_batch, max_inflight,
             # restore can halt us here and refill the queue from the replay
             # log, which re-opens the close_ingress → publish_eof sequence.
             while not done and not exchange.reorder.stopped():
+                clock.end_pass(kind)
                 pump_conn()
                 service_ctrl()
                 if disp.pending():  # drain our queue into the rings
                     if disp.pump():
+                        kind = _BUSY
                         spin = _IDLE_MIN
                     else:
+                        kind = _BLOCKED
                         time.sleep(spin)
                         spin = min(spin * 2, _IDLE_MAX)
                     continue
@@ -1218,6 +1305,7 @@ def _router_main(ridx, upstream, exchange, conn, plan, io_batch, max_inflight,
                 if disp.publish_eof():  # cascade EOF downstream
                     done = True
                 else:
+                    kind = _BLOCKED
                     time.sleep(spin)
                     spin = min(spin * 2, _IDLE_MAX)
             if done and not upstream.has_stashed():
@@ -1229,7 +1317,7 @@ def _router_main(ridx, upstream, exchange, conn, plan, io_batch, max_inflight,
         except Exception:
             pass
     try:
-        conn.send(("stats", f"router{ridx}", busy, 0))
+        clock.end_pass(kind)
         conn.close()
     except Exception:
         pass
@@ -1538,8 +1626,10 @@ class ProcessRuntime:
         self._disp: Optional[_Dispatcher] = None
         self._spills: dict[int, tuple[int, bytes]] = {}
         self._eof_seen = False
-        self._worker_busy = 0.0
-        self._worker_processed = 0
+        # supervisor time inside _service_once, by what it did (plain
+        # in-process counters; the children's live in shared memory)
+        self._ingress_ns = self._egress_ns = self._relay_ns = 0
+        self._final_counters: Optional[dict] = None  # read at stop()
         self.restarts = 0  # crash-recovery instrumentation
 
         # fault-tolerance state (armed per start_stream in _setup)
@@ -1616,6 +1706,44 @@ class ProcessRuntime:
             dict(stage=s, worker=w, **info)
             for (s, w), info in sorted(self._devices.items())
         ]
+
+    def counters(self) -> dict:
+        """Where each process's time went, as monotone counters: per stage,
+        one dict per ingress ring (worker slot) of ``busy_ns``, ``wait_ns``,
+        ``blocked_ns`` and ``rows``, with ``hold_ns``/``hold_units`` on
+        device stages (``stage_counters``); per exchange router, stage 1
+        first, ``busy_ns``/``wait_ns``/``blocked_ns``
+        (``router_counters``); and the supervisor's nanoseconds inside its
+        crank, by ``ingress_ns`` (sealing and routing pushed units),
+        ``egress_ns`` (final drain and tail) and ``relay_ns`` (child pipes:
+        spill bodies relayed) (``supervisor_counters``).  Live while the
+        stream runs; the last values once it stopped."""
+        if not self._exchanges:
+            return self._final_counters or {
+                "stage_counters": [], "router_counters": [],
+                "supervisor_counters": {"ingress_ns": 0, "egress_ns": 0,
+                                        "relay_ns": 0},
+            }
+        names = shm.ShmSpscRing.COUNTERS
+        stages = []
+        for x, plan in zip(self._exchanges, self.stage_plans):
+            keep = names if plan.kind == "device" else names[:_HOLD_NS]
+            stages.append([
+                {k: c[k] for k in keep}
+                for c in (r.read_counters() for r in x.rings)
+            ])
+        seal = self._disp.seal_ns if self._disp is not None else 0
+        return {
+            "stage_counters": stages,
+            "router_counters": [
+                x.reorder.read_counters() for x in self._exchanges[:-1]
+            ],
+            "supervisor_counters": {
+                "ingress_ns": self._ingress_ns + seal,
+                "egress_ns": self._egress_ns,
+                "relay_ns": self._relay_ns,
+            },
+        }
 
     def worker_groups(self) -> list[list[multiprocessing.Process]]:
         """Live worker processes per stage (crash tests / introspection)."""
@@ -1752,6 +1880,8 @@ class ProcessRuntime:
         )
         self._eof_seen = False
         self._devices = {}
+        self._ingress_ns = self._egress_ns = self._relay_ns = 0
+        self._final_counters = None
         self._monitor = None
         self._traffic = None
         if self.elastic and any(p.resizable for p in self.stage_plans):
@@ -1806,6 +1936,8 @@ class ProcessRuntime:
                 conn.close()
             except Exception:
                 pass
+        if self._exchanges:  # every child is gone: the counters are final
+            self._final_counters = self.counters()
         for x in self._exchanges:
             x.close()
             x.unlink()
@@ -1899,9 +2031,6 @@ class ProcessRuntime:
                 raise RuntimeError(ONE_CHIP_OWNER.format(n=self.chip_owners))
         elif kind == "halted":  # router acked a group-restore halt
             self._halted.add(msg[1])
-        elif kind == "stats":
-            self._worker_busy += msg[2]
-            self._worker_processed += msg[3]
         elif kind == "marks":  # probes dropped mid-pipeline (filtered tuples)
             for m in msg[1]:
                 self._record_dropped(m)
@@ -1952,7 +2081,7 @@ class ProcessRuntime:
             if p is None or p.is_alive():
                 continue
             # Salvage every message first — a user-fn error beats a crash
-            # diagnosis, and spills/stats must not be lost.
+            # diagnosis, and spills must not be lost.
             try:
                 while self._conns[idx].poll():
                     self._on_message(idx, self._conns[idx].recv())
@@ -2560,20 +2689,27 @@ class ProcessRuntime:
         elastic replanning).  Returns True if anything moved."""
         progress = False
         disp = self._disp
+        clock = time.perf_counter_ns
+        t0 = clock()
         if disp.pump():
             progress = True
         if self._src_done and not self._eof_published and not disp.pending():
             if disp.publish_eof():
                 self._eof_published = True
                 progress = True
+        t1 = clock()
         if self._drain_final():
             progress = True
         if progress and self._tail is not None:
             self._pump_tail()
+        t2 = clock()
+        self._ingress_ns += t1 - t0
+        self._egress_ns += t2 - t1
         now = time.perf_counter()
         if now >= self._monitor_at:
             self._monitor_at = now + 0.02
             self._drain_conns()
+            self._relay_ns += clock() - t2
             if self._fault_queue:
                 self._drive_faults(now)
             self._check_procs()
@@ -2796,7 +2932,11 @@ class ProcessRuntime:
         n_procs = sum(p.workers for p in self.stage_plans) + max(
             len(self.stage_plans) - 1, 0
         )
-        busy = self._worker_busy / (n_procs * wall) if wall > 0 else 0.0
+        ctr = self.counters()
+        busy_ns = sum(
+            w["busy_ns"] for group in ctr["stage_counters"] for w in group
+        ) + sum(r["busy_ns"] for r in ctr["router_counters"])
+        busy = busy_ns * 1e-9 / (n_procs * wall) if wall > 0 else 0.0
         window = wall
         if self._first_push_ts is not None and last_out is not None:
             window = max(last_out - self._first_push_ts, 1e-9)

@@ -23,6 +23,9 @@ Ring wire format
   back over its pipe).  Consumption is split into :meth:`ShmSpscRing.peek` /
   :meth:`ShmSpscRing.advance` so a consumer can *read* a record, act on it,
   and only then commit the head — the basis of crash replay (below).
+  Offsets 32 and up are consumer-owned monotone counters: the heartbeat,
+  then the stage counters (:attr:`ShmSpscRing.COUNTERS`) that any process
+  may sample.
 
 - :class:`ShmReorderRing` — the cross-process mirror of
   :class:`~.reorder.NonBlockingReorderBuffer` (paper fig. 4): a bounded ring
@@ -225,10 +228,18 @@ class ShmSpscRing:
     peek+advance convenience for consumers that do not need replay.
     """
 
-    _HDR = 64  # tail:8 @0 (producer-owned), head:8 @8 (consumer-owned),
+    _HDR = 128  # tail:8 @0 (producer-owned), head:8 @8 (consumer-owned),
     # closed:8 @16 (producer-owned), handoff:8 @24 (supervisor-owned),
-    # heartbeat:8 @32 (consumer-owned monotone liveness counter)
+    # heartbeat:8 @32 (consumer-owned monotone liveness counter),
+    # stage counters:8 each @40.. (consumer-owned, monotone; COUNTERS)
     _REC = struct.Struct("<IBq")  # total_len, tag, serial
+    #: the consumer's monotone stage counters, in header order from @40:
+    #: nanoseconds busy, waiting for input and blocked on a full window,
+    #: rows processed, and (device stages) nanoseconds units were held
+    #: inside the stage and the units held
+    COUNTERS = ("busy_ns", "wait_ns", "blocked_ns", "rows", "hold_ns",
+                "hold_units")
+    _CTR = struct.Struct(f"<{len(COUNTERS)}q")
 
     def __init__(self, name_prefix: str, slots: int = 4096, slot_bytes: int = 512):
         if slots < 4:
@@ -244,6 +255,8 @@ class ShmSpscRing:
         self._tail = 0  # producer-side mirror
         self._head = 0  # consumer-side mirror
         self._beat = 0  # consumer-side heartbeat mirror
+        #: consumer-side mirror of the stage counters (see :attr:`COUNTERS`)
+        self.counters = [0] * len(self.COUNTERS)
         self.name = self._shm.name
 
     @property
@@ -352,14 +365,27 @@ class ShmSpscRing:
         """Current consumer heartbeat value (supervisor-side sample)."""
         return self._load(32)
 
+    # -- stage counters (consumer writes, any process reads) ----------------
+    def store_counters(self) -> None:
+        """Consumer-side: publish the :attr:`counters` mirror — one aligned
+        8-byte store per counter, at most once per loop pass."""
+        self._CTR.pack_into(self._buf, 40, *self.counters)
+
+    def read_counters(self) -> dict:
+        """The consumer's stage counters by name (any-process sample)."""
+        return dict(zip(self.COUNTERS, self._CTR.unpack_from(self._buf, 40)))
+
     # -- consumer -----------------------------------------------------------
     def sync_consumer(self) -> None:
         """Reload the consumer cursor from shared memory.
 
         A replacement consumer process (crash re-fork) inherits the parent's
         stale head mirror; this re-reads the authoritative shared value so it
-        resumes exactly at the first uncommitted record."""
+        resumes exactly at the first uncommitted record — and its
+        predecessor's stage counters, so they stay monotone across a
+        re-fork."""
         self._head = self._load(8)
+        self.counters = list(self._CTR.unpack_from(self._buf, 40))
 
     def peek(self) -> Optional[Tuple[int, int, bytes, int]]:
         """Read the head record WITHOUT committing it.
@@ -452,8 +478,14 @@ class ShmReorderRing:
     _HDR = 128  # next:8 @0 (drainer-owned), stop:8 @8 (supervisor-owned),
     # active group width:8 @16 (supervisor-owned metadata),
     # drainer heartbeat:8 @24, commit record slots A/B:16 @32/@48
-    # (read_pos, downstream serial), active record index:8 @64 (0 = none)
+    # (read_pos, downstream serial), active record index:8 @64 (0 = none),
+    # drainer counters:8 each @72.. (drainer-owned, monotone; COUNTERS)
     _SLOT_HDR = struct.Struct("<qIIB")  # seq, len, span, tag
+    #: the drainer's (an exchange router's) monotone counters from @72:
+    #: nanoseconds busy, waiting for upstream results, and blocked on the
+    #: downstream stage
+    COUNTERS = ("busy_ns", "wait_ns", "blocked_ns")
+    _CTR = struct.Struct(f"<{len(COUNTERS)}q")
 
     PUBLISHED = 0
     FULL = 1
@@ -482,6 +514,8 @@ class ShmReorderRing:
         # entries wait here until the contiguous sweep reaches them.  Bounded
         # by the ring window (every stashed serial is < next + size).
         self._stash: dict = {}
+        #: drainer-side mirror of the drainer counters (see :attr:`COUNTERS`)
+        self.counters = [0] * len(self.COUNTERS)
         self.name = self._shm.name
 
     # -- worker side --------------------------------------------------------
@@ -587,7 +621,10 @@ class ShmReorderRing:
         and return the downstream serial to resume dispatch at.  Also
         re-publishes the window at the committed position — a predecessor
         killed between writing the record and widening the window left the
-        two an index apart, and the record is the later, authoritative one."""
+        two an index apart, and the record is the later, authoritative one.
+        The predecessor's drainer counters are reloaded too (monotone across
+        a re-fork)."""
+        self.counters = list(self._CTR.unpack_from(self._buf, 72))
         rec = self.commit_record()
         if rec is None:
             self._next = _I8.unpack_from(self._buf, 0)[0]
@@ -616,6 +653,16 @@ class ShmReorderRing:
     def drainer_heartbeat(self) -> int:
         """Current drainer heartbeat value (supervisor-side sample)."""
         return _I8.unpack_from(self._buf, 24)[0]
+
+    # -- drainer counters (drainer writes, any process reads) ---------------
+    def store_counters(self) -> None:
+        """Drainer-side: publish the :attr:`counters` mirror (see
+        :meth:`ShmSpscRing.store_counters`)."""
+        self._CTR.pack_into(self._buf, 72, *self.counters)
+
+    def read_counters(self) -> dict:
+        """The drainer's counters by name (any-process sample)."""
+        return dict(zip(self.COUNTERS, self._CTR.unpack_from(self._buf, 72)))
 
     @property
     def next_serial(self) -> int:
