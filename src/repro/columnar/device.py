@@ -311,6 +311,51 @@ def device_op(
     )
 
 
+def dtype_groups(dtypes) -> List[Tuple[np.dtype, List[int]]]:
+    """Column indices by dtype, dtypes in order of first use: the layout
+    of the packed buffers, one ``(len(indices), rows)`` buffer each."""
+    groups: Dict[np.dtype, List[int]] = {}
+    for i, dt in enumerate(dtypes):
+        groups.setdefault(np.dtype(dt), []).append(i)
+    return list(groups.items())
+
+
+def packed_program(fn: Callable[..., tuple], dtypes) -> Callable[..., tuple]:
+    """The column map ``fn`` over packed buffers, for ``jax.jit``: the
+    buffers (laid out by :func:`dtype_groups`) are taken apart into
+    columns, ``fn`` is applied, and its outputs are packed the same way."""
+    groups = dtype_groups(dtypes)
+
+    def program(*bufs):
+        import jax.numpy as jnp
+
+        outs = fn(*unpack(bufs, groups, len(dtypes)))
+        # before packing, which would promote a stray dtype silently
+        _check_dtypes(outs, dtypes)
+        return tuple(jnp.stack([outs[i] for i in idx]) for _, idx in groups)
+
+    return program
+
+
+def unpack(bufs, groups, width: int, stop: Optional[int] = None) -> list:
+    """The ``width`` columns held in packed ``bufs`` laid out by ``groups``
+    (:func:`dtype_groups`), each cut to its first ``stop`` rows."""
+    cols: list = [None] * width
+    for buf, (_, idx) in zip(bufs, groups):
+        for j, i in enumerate(idx):
+            cols[i] = buf[j, :stop]
+    return cols
+
+
+def _check_dtypes(cols, dtypes) -> None:
+    for c, dt in zip(cols, dtypes):
+        if c.dtype != dt:
+            raise TypeError(
+                f"device kernel returned {c.dtype} for a {dt} column; "
+                "a device stage computes in its schema's dtypes"
+            )
+
+
 class DeviceExecutor:
     """Double-buffered batch executor behind a device-stage worker.
 
@@ -334,12 +379,20 @@ class DeviceExecutor:
     (``lower_s``) and compiling (``compile_s``, what the persistent cache
     saves) over ``compiles`` shapes; ``None`` on the NumPy backend.
 
+    On the jax backend the columns travel packed: one ``(columns, rows)``
+    buffer per dtype of the schema, so a dispatch makes one host-to-device
+    transfer and one read-back per dtype, whatever the column count.  The
+    compiled program takes the packed buffers apart, calls the kernel on
+    the columns, and packs its outputs the same way; ``device`` counts the
+    transfers (``h2d_transfers``, ``d2h_transfers``).
+
     Each submitted block is stamped (``held_since``) and the stamp rides
     its unit back out, so the caller can tell how long a unit was held.  A
     jax executor arms :func:`repro.core.trace.span` in its process: each
-    dispatch records ``stream.device.dispatch`` (zero-padded columns up,
-    and the launch) and each synchronisation ``stream.device.sync`` (the
-    wait and the columns back down) where a profiler runs."""
+    dispatch records ``stream.device.dispatch`` (the zero-padded packed
+    buffers up, and the launch) and each synchronisation
+    ``stream.device.sync`` (the wait and the packed buffers back down)
+    where a profiler runs."""
 
     def __init__(
         self,
@@ -358,11 +411,13 @@ class DeviceExecutor:
         self._fn = make_kernel(kernel, self.backend, params)
         self.device: Optional[Dict[str, Any]] = None
         self._executables: Dict[int, Any] = {}
+        self._groups = dtype_groups(self.schema.dtypes)
         if self.backend == "jax":
             import jax
 
             self.device = self._bring_up(spec.name)
-            self._fn = jax.jit(self._fn)
+            self._packed = jax.jit(
+                packed_program(self._fn, self.schema.dtypes))
             self._executable(self.batch)
             trace.arm(jax.profiler.TraceAnnotation)
         self._pending: List[ColumnBlock] = []
@@ -400,18 +455,21 @@ class DeviceExecutor:
             "lower_s": 0.0,
             "compile_s": 0.0,
             "compiles": 0,
+            "h2d_transfers": 0,
+            "d2h_transfers": 0,
         }
 
     def _executable(self, rows: int):
-        """The kernel compiled for ``rows``-row columns (compiled once per
-        shape, timed into ``device``)."""
+        """The packed program compiled for ``rows``-row columns (compiled
+        once per shape, timed into ``device``)."""
         exe = self._executables.get(rows)
         if exe is None:
             import jax
 
             t0 = time.perf_counter()
-            lowered = self._fn.lower(*(
-                jax.ShapeDtypeStruct((rows,), dt) for dt in self.schema.dtypes
+            lowered = self._packed.lower(*(
+                jax.ShapeDtypeStruct((len(idx), rows), dt)
+                for dt, idx in self._groups
             ))
             t1 = time.perf_counter()
             exe = lowered.compile()
@@ -465,16 +523,18 @@ class DeviceExecutor:
         with trace.span(trace.DEVICE_DISPATCH):
             if self.backend == "jax":
                 rows = -(-n // self.batch) * self.batch
-                cols = []
-                for i, dt in enumerate(self.schema.dtypes):
+                bufs = []
+                for dt, idx in self._groups:
                     # fresh buffer, zero-padded to the compiled shape: safe
                     # to alias zero-copy, the host never mutates it after
                     # dispatch
-                    col = np.zeros(rows, dt)
-                    np.concatenate([b.columns[i] for b in blocks],
-                                   out=col[:n])
-                    cols.append(col)
-                outs = self._executable(rows)(*cols)
+                    buf = np.zeros((len(idx), rows), dt)
+                    for j, i in enumerate(idx):
+                        np.concatenate([b.columns[i] for b in blocks],
+                                       out=buf[j, :n])
+                    bufs.append(buf)
+                outs = self._executable(rows)(*bufs)
+                self.device["h2d_transfers"] += len(bufs)
             else:
                 outs = self._fn(*ColumnBlock.concat(blocks).columns)
         self.dispatches += 1
@@ -486,14 +546,13 @@ class DeviceExecutor:
             if self.backend == "jax":
                 import jax
 
-                outs = jax.block_until_ready(outs)
-            cols = [np.asarray(o)[:n] for o in outs]
-        for c, dt in zip(cols, self.schema.dtypes):
-            if c.dtype != dt:
-                raise TypeError(
-                    f"device kernel returned {c.dtype} for a {dt} column; "
-                    "a device stage computes in its schema's dtypes"
-                )
+                packed = [np.asarray(o) for o in jax.block_until_ready(outs)]
+                self.device["d2h_transfers"] += len(packed)
+                cols = unpack(packed, self._groups, len(self.schema.dtypes),
+                              n)
+            else:
+                cols = [np.asarray(o)[:n] for o in outs]
+        _check_dtypes(cols, self.schema.dtypes)
         blocks: List[ColumnBlock] = []
         off = 0
         for serials, marks, held_since in units:

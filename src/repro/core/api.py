@@ -852,7 +852,9 @@ class JobResult:
     ``on_error="dead_letter"`` policy).  ``devices`` has one row per jax
     device worker of a process run — ``stage``, ``worker``, and the
     ``platform``, device ``kind`` and ``count`` its backend reported, with
-    its ``lower_s``, ``compile_s``, ``compiles`` and ``dispatches``.  ``handle()`` wraps
+    its ``lower_s``, ``compile_s``, ``compiles`` and ``dispatches``, and
+    the host-to-device and device-to-host transfers its dispatches made
+    (``h2d_transfers``, ``d2h_transfers``).  ``handle()`` wraps
     it in the legacy-shaped proxy."""
 
     outputs: list

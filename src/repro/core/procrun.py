@@ -1700,8 +1700,9 @@ class ProcessRuntime:
     def device_reports(self) -> List[dict]:
         """One row per jax device worker: ``stage``, ``worker`` and what its
         backend reported — ``platform``, device ``kind`` and ``count`` as
-        jax sees them, ``lower_s``/``compile_s``/``compiles``, and (once the
-        worker has exited) ``dispatches``."""
+        jax sees them, ``lower_s``/``compile_s``/``compiles``, the
+        ``h2d_transfers``/``d2h_transfers`` its dispatches made (0 until the
+        worker has exited), and (once it has exited) ``dispatches``."""
         return [
             dict(stage=s, worker=w, **info)
             for (s, w), info in sorted(self._devices.items())

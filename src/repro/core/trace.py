@@ -19,8 +19,8 @@ from typing import Callable, Optional
 
 DEVICE_WAIT = "stream.device.wait"  # an empty input ring until the next record
 DEVICE_DECODE = "stream.device.decode"  # one unit, off the wire into columns
-DEVICE_DISPATCH = "stream.device.dispatch"  # padded columns up (H2D), launch
-DEVICE_SYNC = "stream.device.sync"  # wait for a dispatch, columns down (D2H)
+DEVICE_DISPATCH = "stream.device.dispatch"  # padded packed buffers up, launch
+DEVICE_SYNC = "stream.device.sync"  # wait for a dispatch, packed buffers down
 DEVICE_PUBLISH = "stream.device.publish"  # one unit, encoded and published
 DEVICE_SPANS = (DEVICE_WAIT, DEVICE_DECODE, DEVICE_DISPATCH, DEVICE_SYNC,
                 DEVICE_PUBLISH)

@@ -57,6 +57,19 @@ def test_affine_pallas_compiles_for_v5e(one_chip, rows):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("columns", [12, 24])
+def test_packed_device_program_compiles_for_v5e(one_chip, columns):
+    """The device stage's packed program at 256-row dispatches, for a
+    12-column and a 24-column int32 schema: one ``(columns, 256)`` buffer
+    each way, taken apart into one Mosaic kernel per column."""
+    from repro.columnar.device import make_kernel, packed_program
+
+    fn = make_kernel("affine_pallas", "jax", (("a", 10), ("b", 0)))
+    buf = jax.ShapeDtypeStruct((columns, 256), jnp.int32, sharding=one_chip)
+    text = _compiled_text(packed_program(fn, [jnp.int32] * columns), buf)
+    assert text.count("tpu_custom_call") == columns
+
+
 def test_flash_attention_compiles_for_v5e_at_olmo_1b_width(one_chip):
     """Flash attention at olmo-1b's head_dim 128 and 16 heads, seq 2048."""
     from repro.configs.olmo_1b import CONFIG

@@ -306,6 +306,102 @@ def test_device_executor_preserves_unit_boundaries(sizes, batch):
         assert blk.marks == marks
 
 
+def _drive_executor(schema, kernel, backend, sizes, batch, row):
+    """Units of ``sizes`` rows (``row(v)`` each) through a DeviceExecutor;
+    returns the executor and each returned unit's serials, marks and
+    column bytes."""
+    spec = device_op("dev", kernel, schema, params={"a": 3, "b": -7},
+                     backend=backend)
+    ex = DeviceExecutor(spec, batch=batch, inflight=2)
+    serial, outs = 1, []
+    for n in sizes:
+        vals = [row(v) for v in range(serial, serial + n)]
+        blk = ColumnBlock.from_values(vals, head_serial=serial,
+                                      marks=[(0, f"mark{serial}")],
+                                      schema=schema)
+        outs.extend(ex.submit(blk))
+        serial += n
+    outs.extend(ex.flush())
+    assert ex.pending_rows == 0 and ex.inflight == 0
+    return ex, [(b.serials.tolist(), b.marks,
+                 [c.tobytes() for c in b.columns]) for b in outs]
+
+
+@settings(max_examples=15, deadline=None)
+@given(
+    sizes=st.lists(st.integers(min_value=1, max_value=9), min_size=1,
+                   max_size=20),
+    batch=st.integers(min_value=1, max_value=16),
+)
+def _packed_executor_preserves_unit_boundaries(sizes, batch):
+    schema = Schema.of("i4", "i4", "i4")
+
+    def row(v):
+        return (v, -2 * v, v * v)
+
+    _, want = _drive_executor(schema, "affine", "numpy", sizes, batch, row)
+    ex, got = _drive_executor(schema, "affine", "jax", sizes, batch, row)
+    assert got == want
+    heads = [1 + sum(sizes[:k]) for k in range(len(sizes))]
+    assert [s for s, _, _ in got] == [
+        list(range(h, h + n)) for h, n in zip(heads, sizes)]
+    assert ex.device["h2d_transfers"] == ex.device["d2h_transfers"] \
+        == ex.dispatches > 0
+
+
+def _packed_executor_mixed_dtypes():
+    schema = Schema.of("i4", "f4", "i4", "f4")
+
+    def row(v):
+        return (v, v * 0.37, -v, v / 7)
+
+    sizes = [3, 7, 16, 1, 9, 30, 2]
+    _, want = _drive_executor(schema, "square", "numpy", sizes, 16, row)
+    ex, got = _drive_executor(schema, "square", "jax", sizes, 16, row)
+    assert got == want
+    assert ex.device["h2d_transfers"] == ex.device["d2h_transfers"] \
+        == 2 * ex.dispatches > 0
+
+
+def _in_fresh_interpreter(check) -> None:
+    """Run ``check``, a function of this module, in a new interpreter: a
+    jax backend brought up in the test process would make every jax device
+    worker it later forks refuse to start (``jax_fork_hazard``)."""
+    import os
+    import subprocess
+    import sys
+
+    tests = os.path.dirname(os.path.abspath(__file__))
+    src = os.path.join(os.path.dirname(tests), "src")
+    proc = subprocess.run(
+        [sys.executable, "-c",
+         f"import test_columnar as t; t.{check.__name__}()"],
+        capture_output=True, text=True, timeout=110, cwd=tests,
+        env={**os.environ, "PYTHONPATH": os.pathsep.join([src, tests])},
+    )
+    assert proc.returncode == 0, proc.stderr[-3000:]
+
+
+@pytest.mark.timeout(120)
+def test_jax_device_executor_packs_and_preserves_unit_boundaries():
+    """On the jax backend the columns travel packed, one transfer each way
+    per dispatch for a one-dtype schema, and units still come back whole
+    and in order, with serials, marks and values identical to the NumPy
+    backend's, however units regroup into device batches."""
+    if not have_jax():
+        pytest.skip("jax not installed; the packed path needs jax")
+    _in_fresh_interpreter(_packed_executor_preserves_unit_boundaries)
+
+
+@pytest.mark.timeout(120)
+def test_jax_device_executor_mixed_dtypes_match_reference():
+    """A mixed i4/f4 schema packs into one buffer per dtype (two transfers
+    each way per dispatch) and matches the NumPy backend bit for bit."""
+    if not have_jax():
+        pytest.skip("jax not installed; the packed path needs jax")
+    _in_fresh_interpreter(_packed_executor_mixed_dtypes)
+
+
 def test_device_op_rejects_bad_construction():
     with pytest.raises(ValueError):
         device_op("d", "no_such_kernel", Schema.of("i8"))
