@@ -37,6 +37,7 @@ import faults
 import loadgen
 import peaks
 import probe as probe_mod
+import spanreduce
 import tpcds
 import tracereduce
 import work
@@ -173,6 +174,7 @@ class Run:
         self.read_t, self.read_n = array("d"), array("q")
         self.push_t, self.push_lo = array("d"), array("q")
         self.samples: list = []
+        self.last_stats: dict | None = None  # the newest sample's stats()
         self.counters: list = []
         self.long_calls: list = []
         self.procs: dict = {}
@@ -184,7 +186,7 @@ class Run:
 
         kernel = faults.kernel_for(self.fault)
         ops = self.cell["query"].build(self.cfg, tpcds.COLUMNS, kernel)
-        ops = faults.wrap_ops(ops, self.fault)
+        ops = self.ops = faults.wrap_ops(ops, self.fault)
         lay = self.layout
         engine = Engine(EngineConfig(
             backend="process", num_workers=lay["host_workers"],
@@ -256,7 +258,7 @@ class Run:
         if now < self._next_sample:
             return
         self._next_sample = now + SAMPLE_S
-        st = session.stats()
+        st = self.last_stats = session.stats()
         self.samples.append({"t": now, "backlog": st["backlog_slots"]})
         if now >= self._next_counters:
             self._next_counters = now + COUNTERS_S
@@ -395,6 +397,9 @@ class Run:
                                           side="right"))
             self._paced(session, w1)
             call_s = self.session_call_s
+            # the counters at the window's end: its last sample, since the
+            # answers due in the window are still being drained
+            st_end = self.last_stats
             self._paced(session, w1, until_read=due_end,
                         deadline=w1 + DRAIN_TIMEOUT_S)
         else:
@@ -404,6 +409,10 @@ class Run:
         gc.callbacks.remove(gc_pauses)
         st1 = session.stats()
         cpu1 = _cpu_times(self.procs)
+        if not rated:
+            st_end = st1
+        counters = spanreduce.counter_deltas(
+            st0, st_end, [s.kind for s in plan.stages])
         self._next_sample = self._next_counters = float("inf")
         # events egressed in the window, as the runtime counts them
         in_window = st1["egressed"] - st0["egressed"]
@@ -460,6 +469,7 @@ class Run:
             "rows_per_dispatch_run": (self.pushed / dispatches
                                       if dispatches else None),
             "dispatches": dispatches,
+            "stage_counters": counters,
             "parent_gc": gc_pauses.summary(),
             "cpu_busy": {k: (cpu1[k] - cpu0[k]) / (t_end - w0)
                          for k in cpu0 if k in cpu1 and cpu0[k] is not None
@@ -496,7 +506,8 @@ class Run:
         }
         if self.trace:
             per_layer, summary = self._per_layer(
-                run_dir, trace_info, dev, plan, w0, w1, late, call_s, diag)
+                run_dir, trace_info, dev, plan, w0, w1, late, call_s,
+                counters, diag)
             result["metrics"] = per_layer
             result["device"].update(busy_s=summary["busy_s"],
                                     window_s=summary["window_s"])
@@ -557,7 +568,7 @@ class Run:
         }
 
     def _per_layer(self, run_dir, trace_info, dev, plan, w0, w1, late,
-                   call_s, diag) -> dict:
+                   call_s, counters, diag) -> dict:
         path = tracereduce.newest_xplane(str(run_dir / "trace"))
         t0 = self.t_trace0
         t1 = trace_info["t"]
@@ -584,6 +595,8 @@ class Run:
             "stage_bytes": lambda rows: work.device_stage_bytes(rows, codes),
             "ring_backlog": ring_backlog, "session_call_s": call_s,
             "window_s": w1 - w0, "feeder_late_s": late, "reader": reader,
+            "stage_counters": counters, "cfg": self.cfg,
+            "device_op": next(op for op in self.ops if op.kind == "device"),
         }
         per_layer = {}
         for m in self.cell["per_layer"]:

@@ -3,6 +3,14 @@ chip, and print the program's spans in the trace as one JSON line:
 ``{"spans": {name: [count, total_s]}, "dispatches": n}``.
 
     JAX_PLATFORMS=cpu python _cpu_trace.py
+    JAX_PLATFORMS=cpu python _cpu_trace.py WORKLOAD [WORKLOAD ...]
+
+With workloads named, make one traced run of each cell through the
+harness at a tiny size instead, and print for each one JSON line: its
+per-layer metrics as ``Run._per_layer`` read them, and what the readers'
+context held of the cell (``cfg`` and ``device_op``).  The CPU has no
+device plane and no published peaks, so XLA's CPU threads stand in for
+the plane and a TPU v5e's peaks for the CPU's.
 
 The parent never brings up a jax backend; the device worker does, and the
 probe's fork hook runs the profiler inside it.
@@ -31,7 +39,60 @@ def drive(session, n0: int, n1: int) -> None:
     assert got == [(3 * v + 1, 6 * v + 1) for v in range(n0, n1)], got[:3]
 
 
+def traced_run(workload: str) -> dict:
+    """One traced run of ``workload`` through the harness on the CPU."""
+    import harness
+    import peaks
+
+    seen = {}
+    real_extract, real_reader = tracereduce.extract, harness.reader
+    real_peaks = peaks.peaks
+
+    def extract(path):
+        return [["/device:TPU:0", "XLA Ops", *e[2:]]
+                if e[1].startswith("tf_XLA") else e
+                for e in real_extract(path)]
+
+    def reader(name):
+        read = real_reader(name)
+
+        def spy(ctx):
+            op = ctx["device_op"]
+            kernel, params = op.device_kernel
+            seen.update(cfg=ctx["cfg"]["name"], device_op={
+                "name": op.name, "kind": op.kind,
+                "columns": len(op.schema.fields), "kernel": kernel,
+                "params": dict(params)})
+            return read(ctx)
+
+        return spy
+
+    tracereduce.extract, harness.reader = extract, reader
+    peaks.peaks = lambda kind: real_peaks("TPU v5 lite")
+    try:
+        cell = harness.load_cell(workload)
+        cell["cfg"]["pool_rows"] = 2048
+        cell["mix"]["warmup_s"] = 0.3
+        out = harness.Run(cell, 2 ** 31 + 777, 1.0, True,
+                          require_chip=False, layout={"host_workers": 1},
+                          check_cores=False).execute()
+    finally:
+        tracereduce.extract, harness.reader = real_extract, real_reader
+        peaks.peaks = real_peaks
+    res = out["result"]
+    return {"workload": workload, "correct": res["correct"],
+            "metrics": {k: m["value"] for k, m in res["metrics"].items()},
+            "breakdown": sorted(res["breakdown"]),
+            "stage_counters": out["diag"]["stage_counters"] is not None,
+            "idle_by_span": out["diag"]["trace_summary"]["idle_by_span"],
+            **seen}
+
+
 def main() -> None:
+    if sys.argv[1:]:
+        for workload in sys.argv[1:]:
+            print(json.dumps(traced_run(workload)), flush=True)
+        return
     from repro.columnar import Schema, device_op
     from repro.core import Engine, EngineConfig, OpSpec, ProcessOptions
 

@@ -1,6 +1,8 @@
 """``store_sales_item`` on the CPU at a tiny size: the program's egress
 equals the reference exactly, and every fault planted under the timed
-path, and every control, makes ``correct`` false."""
+path, and every control, makes ``correct`` false; the same of
+``store_sales_item_uniform``, the query with items drawn uniformly, for a
+sound run, ``low_digit`` and the controls."""
 import sys
 from pathlib import Path
 
@@ -17,6 +19,11 @@ FAULTS = ["device_identity", "half_batch", "low_digit", "state_unchanged",
 @pytest.fixture(scope="module")
 def lines():
     return run_scenarios("store_sales_item.saturate", ["sound", *FAULTS])
+
+
+@pytest.fixture(scope="module")
+def uniform():
+    return run_scenarios("store_sales_item.uniform", ["sound", "low_digit"])
 
 
 @pytest.fixture(scope="module")
@@ -47,3 +54,21 @@ def test_fault_is_not_correct(lines, fault):
 def test_rated_run_matches_reference_and_reports_latency(rated):
     assert rated["correct"], rated["checks"]
     assert rated["metrics"] == ["latency_p50_ms", "setup_s"]
+
+
+def test_uniform_sound_run_matches_reference(uniform):
+    sound = uniform["sound"]
+    assert sound["correct"], sound["checks"]
+    assert sound["attempted"] > 0
+    assert sound["metrics"] == ["setup_s", "throughput_eps"]
+
+
+@pytest.mark.parametrize("control", ["duplicate_delivery", "reorder_pair"])
+def test_uniform_control_is_not_correct(uniform, control):
+    assert uniform["sound"]["controls"][control] is False
+
+
+def test_uniform_low_digit_is_not_correct(uniform):
+    line = uniform["low_digit"]
+    assert line["correct"] is False
+    assert line["checks"]["mismatched_rows"]["value"] > 0

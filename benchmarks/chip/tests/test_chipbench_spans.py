@@ -167,7 +167,8 @@ def test_each_new_reader_returns_nothing_on_the_old_context(name):
            "reader": harness.reader}
     assert harness.reader(name)(old) is None
     # a trace of a program without spans, counters of a plan that moved
-    old["trace"] = tracereduce.reduce(EVENTS)
+    old["trace"] = tracereduce.reduce(
+        [e for e in EVENTS if not e[2].startswith(spanreduce.PREFIX)])
     old["stage_counters"] = None
     assert harness.reader(name)(old) is None
 
@@ -189,3 +190,49 @@ def test_device_worker_traced_on_the_cpu_records_every_span():
     assert spans[trace.DEVICE_SYNC][0] == got["dispatches"]
     assert spans[trace.DEVICE_DECODE][0] == spans[trace.DEVICE_PUBLISH][0]
     assert spans[trace.DEVICE_WAIT][0] >= 1
+
+
+#: cells whose traced runs, between them, list every reader in READERS
+TRACED_CELLS = ("store_sales_item.uniform", "store_sales_item.rated")
+
+
+@pytest.fixture(scope="module")
+def traced_runs():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run(
+        [sys.executable, str(CHIP / "tests" / "_cpu_trace.py"),
+         *TRACED_CELLS],
+        capture_output=True, text=True, timeout=300, env=env,
+        cwd=str(ROOT))
+    lines = [json.loads(x) for x in proc.stdout.splitlines()
+             if x.startswith("{")]
+    assert proc.returncode == 0 and len(lines) == len(TRACED_CELLS), (
+        proc.stderr[-4000:])
+    return {line["workload"]: line for line in lines}
+
+
+@pytest.mark.parametrize("workload", TRACED_CELLS)
+def test_traced_run_fills_its_readers_through_per_layer(traced_runs,
+                                                        workload):
+    got = traced_runs[workload]
+    cell = harness.load_cell(workload)
+    listed = {m["name"] for m in cell["per_layer"]} & set(READERS)
+    assert listed and listed <= set(got["metrics"]), got["metrics"]
+    assert all(got["metrics"][name] > 0 for name in listed
+               if name != "device_worker_blocked_frac")
+    assert got["correct"] and got["stage_counters"]
+    assert got["breakdown"] == ["device_ops", "idle_gaps"]
+    assert got["idle_by_span"][trace.DEVICE_WAIT] > 0
+    # the readers' context holds the cell's configuration and device op
+    assert got["cfg"] == cell["config"]
+    assert got["device_op"] == {
+        "name": "price_convert", "kind": "device",
+        "columns": len(cell["cfg"]["projection"]), "kernel": "affine_pallas",
+        "params": {"a": 10, "b": 0}}
+
+
+def test_the_traced_cells_list_every_new_reader():
+    listed = set()
+    for workload in TRACED_CELLS:
+        listed |= {m["name"] for m in harness.load_cell(workload)["per_layer"]}
+    assert set(READERS) <= listed

@@ -64,6 +64,23 @@ def test_item_skew_is_zipf_and_its_head_does_not_move_with_the_seed():
     assert 0.11 < share < 0.15  # about 13% on the hottest item
 
 
+def test_uniform_items_spread_the_pool_over_the_whole_domain():
+    cfg = _cfg("store_sales_item_uniform")
+    item = tpcds.store_sales(cfg, 2 ** 31 + 11, cfg["pool_rows"])[
+        :, tpcds.COL["ss_item_sk"]]
+    _, counts = np.unique(item, return_counts=True)
+    assert counts.max() <= 1e-4 * len(item)  # no item over 0.01% of rows
+    assert len(counts) >= 150_000  # about 188,400 expected of 204,000
+    lo, hi = cfg["domains"]["ss_item_sk"]
+    assert lo <= item.min() and item.max() <= hi
+    # the item configuration's file, but for the skew and what it says
+    item_cfg = _cfg()
+    differ = {k for k in item_cfg if item_cfg[k] != cfg.get(k)}
+    assert differ == {"name", "source", "deployment", "item_zipf",
+                      "keyed_state", "assumed"}
+    assert set(cfg) == set(item_cfg) and cfg["item_zipf"] == 0
+
+
 def test_events_cycle_the_pool_and_stamp_ev_id():
     pool = tpcds.store_sales(_cfg(), 3, 100)
     ev = tpcds.events(pool, 95, 110)
@@ -162,6 +179,21 @@ def test_trace_reduction_on_a_recorded_chip_trace():
         else:
             assert r[key] == want, key
     assert 0 < r["busy_s"] < r["window_s"]
+
+
+def test_recorded_chip_trace_reduces_as_before_spans_were_added():
+    with gzip.open(CHIP / "tests" / "data" / "chip_trace.json.gz",
+                   "rt") as f:
+        rec = json.load(f)
+    before = json.loads((CHIP / "tests" / "data"
+                         / "chip_trace_reduced.json").read_text())
+    r = tracereduce.reduce(rec["events"], rec["window_s"])
+    for key in ("device_ops", "idle_gaps", "op_s", "busy_s", "launches",
+                "window_s", "device_planes"):
+        assert r[key] == before[key], key
+    # the program recorded no spans in that trace: all idle is outside
+    assert r["spans"] == {}
+    assert list(r["idle_by_span"]) == ["outside_program_span"]
 
 
 def test_trace_without_a_device_plane_is_refused():

@@ -7,7 +7,8 @@ whose name starts with ``/device:`` and is not the host's; on it the
 ``XLA Ops`` line holds one event per operation that ran (``XLA Modules``
 one per program launched).  Host planes (``/host:``) hold the device
 worker's own threads, whose events say what the host was doing while the
-device sat idle.
+device sat idle; among them the program's own ``stream.*`` spans, which
+:mod:`spanreduce` reduces over the same window.
 """
 from __future__ import annotations
 
@@ -92,7 +93,11 @@ def reduce(events: list, window_s: float | None = None,
     the union of the device's operation intervals (the ``XLA Ops`` line,
     or every device line where a plane has none), averaged over the
     device planes.  Each idle gap of a device is given to the innermost
-    host event open at its midpoint."""
+    host event open at its midpoint (``idle_gaps``), and to the innermost
+    program span open there (``idle_by_span``); ``spans`` counts and
+    totals the program's spans."""
+    import spanreduce  # it builds on this module's helpers
+
     if not events:
         raise ValueError("the trace holds no events")
     t0 = min(e[3] for e in events)
@@ -129,10 +134,9 @@ def reduce(events: list, window_s: float | None = None,
         for (s, e), name in zip(gaps, names):
             gap_time[name or "no_host_event"] += (e - s) * 1e-9
     n_dev = len(device)
-    window_s = (t1 - t0) * 1e-9
     ranked = sorted(op_time.items(), key=lambda kv: -kv[1])
     return {
-        "window_s": window_s,
+        "window_s": (t1 - t0) * 1e-9,
         "busy_s": busy_total * 1e-9 / n_dev,
         "device_planes": n_dev,
         "op_s": sum(op_time.values()) / n_dev,
@@ -140,4 +144,6 @@ def reduce(events: list, window_s: float | None = None,
         "device_ops": [[k, v] for k, v in ranked[:top]],
         "idle_gaps": [[k, v / n_dev] for k, v in sorted(
             gap_time.items(), key=lambda kv: -kv[1])[:top]],
+        "spans": spanreduce.spans(events, window_s),
+        "idle_by_span": spanreduce.idle_by_span(events, window_s),
     }
